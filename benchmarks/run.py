@@ -453,6 +453,8 @@ def main(argv: list[str] | None = None) -> None:
                     action="append", default=None,
                     help="run only the named section(s); repeatable")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     csv: list[str] = ["name,us_per_call,derived"]
     for key, title, fn in SECTIONS:

@@ -4,7 +4,9 @@ All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling, MXU-aligned
 block shapes) and are *validated* on CPU with ``interpret=True``, which
 executes the kernel body in Python per grid step.  ``should_interpret()``
 selects interpret mode automatically off-TPU so the same call sites work in
-tests, benchmarks, and on real hardware.
+tests, benchmarks, and on real hardware.  ``tests/test_chip_compile.py``
+compiles the serve-path kernels for a described v5e, and ``chip_smoke.py``
+fails if a run on the chip would take the interpret path.
 """
 
 from __future__ import annotations
@@ -12,20 +14,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-#: Pallas-TPU compiler params across JAX versions: ``CompilerParams`` is
-#: the current name, ``TPUCompilerParams`` the 0.4.x one.  Fail loudly at
-#: import time if neither exists — a None here would only surface as an
-#: opaque TypeError deep inside the first pallas_call.
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if CompilerParams is None:  # pragma: no cover - future-jax guard
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; update repro.kernels.common for this JAX version"
-    )
 
 #: MXU systolic array dimension — matmul block shapes must be multiples.
 MXU_DIM = 128

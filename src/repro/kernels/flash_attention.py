@@ -22,7 +22,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import should_interpret
-from repro.kernels import common
 
 _NEG_INF = -1e30
 
@@ -121,7 +120,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),   # running normalizer
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

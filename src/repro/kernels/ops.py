@@ -308,20 +308,15 @@ def paged_gather_coalesced(
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions, replication checks off.
+    """``jax.shard_map`` with the varying-axes check off.
 
-    The bodies below contain Pallas calls (opaque to the replication
-    checker) and claim replicated outputs the checker cannot verify, so
-    the check is disabled — correctness of the claimed specs is what the
-    sharded differential grids (tests/test_kernels_sharded.py) pin down.
+    The bodies below contain Pallas calls (opaque to the checker) and
+    claim replicated outputs the checker cannot verify, so the check is
+    disabled — correctness of the claimed specs is what the sharded
+    differential grids (tests/test_kernels_sharded.py) pin down.
     """
-    try:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except (ImportError, TypeError):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _kv_axes(mesh, num_kv_heads: int, head_dim: int):

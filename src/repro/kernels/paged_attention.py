@@ -12,8 +12,9 @@ path.
 
 Layouts:
   q        [B, Hkv, G, D]     grouped query heads (G = Hq / Hkv)
-  k_pool   [P, page, Hkv, D]  physical pages (shared pool)
-  v_pool   [P, page, Hkv, D]
+  k_pool   [P, page, Hkv, D]  physical pages (shared pool), viewed as
+  v_pool   [P, page, Hkv, D]  [P, page, Hkv*D] so a head's page is one
+                              (page, D) block
   page_table [B, max_pages]   int32, INVALID_PAGE (-1) for unmapped
   seq_lens [B]                int32 tokens currently valid
 
@@ -41,8 +42,8 @@ def _paged_attn_kernel(
     page_table_ref,    # SMEM [B, max_pages]  (prefetched; used by index maps)
     kv_scale_ref,      # SMEM [1] f32 — dequant scale (1.0 when not quantized)
     q_ref,             # VMEM [1, 1, G, D]
-    k_ref,             # VMEM [1, page, 1, D]  (translated burst)
-    v_ref,             # VMEM [1, page, 1, D]
+    k_ref,             # VMEM [1, page, D]  (translated burst, one head)
+    v_ref,             # VMEM [1, page, D]
     o_ref,             # VMEM [1, 1, G, D]
     m_ref, l_ref, acc_ref,
     *,
@@ -69,8 +70,8 @@ def _paged_attn_kernel(
     @pl.when((p * page_size < seq_len) & ((p + 1) * page_size > lo))
     def _body():
         q = q_ref[0, 0]                               # [G, D]
-        k = k_ref[0, :, 0, :]                         # [page, D]
-        v = v_ref[0, :, 0, :]                         # [page, D]
+        k = k_ref[0]                                  # [page, D]
+        v = v_ref[0]                                  # [page, D]
         if quantized:
             # The burst arrived as int8 bytes; upcast in VMEM *after* the
             # DMA so HBM traffic stays at the quantized width.  Dequantize
@@ -134,6 +135,12 @@ def paged_decode_attention(
     assert page == page_size, (page, page_size)
     max_pages = page_table.shape[1]
     scale = scale if scale is not None else d ** -0.5
+    # Merged-W view [P, page, Hkv*D]: head h of a frame is the (page, D)
+    # block at lane offset h*D.  A (1, page, 1, D) block over the 4-D pool
+    # would put a 1 against Hkv in the second-minor dimension, which the
+    # TPU lowering refuses unless Hkv is a multiple of 8.
+    k_pool = k_pool.reshape(n_pages, page, hkv * d)
+    v_pool = v_pool.reshape(n_pages, page, hkv * d)
 
     def kv_index(bi, h, p, seq_lens_ref, page_table_ref, *_):
         del seq_lens_ref
@@ -141,15 +148,15 @@ def paged_decode_attention(
         # Unmapped entries (-1) clamp to frame 0; the kernel's seq_len guard
         # ensures their data is never used.
         frame = jnp.maximum(page_table_ref[bi, p], 0)
-        return (frame, 0, h, 0)
+        return (frame, 0, h)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hkv, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bi, h, p, *_: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
+            pl.BlockSpec((1, page_size, d), kv_index),
+            pl.BlockSpec((1, page_size, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda bi, h, p, *_: (bi, h, 0, 0)),
         scratch_shapes=[

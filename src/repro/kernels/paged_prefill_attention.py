@@ -63,8 +63,8 @@ def _paged_prefill_kernel(
     page_table_ref,    # SMEM [B, max_pages] (prefetched; used by index maps)
     kv_scale_ref,      # SMEM [1] f32 — dequant scale (1.0 when not quantized)
     q_ref,             # VMEM [1, 1, bs, D]  (bs = bq * G flattened rows)
-    k_ref,             # VMEM [1, page, 1, D]  (translated burst)
-    v_ref,             # VMEM [1, page, 1, D]
+    k_ref,             # VMEM [1, page, D]  (translated burst, one head)
+    v_ref,             # VMEM [1, page, D]
     o_ref,             # VMEM [1, 1, bs, D]
     m_ref, l_ref, acc_ref,
     *,
@@ -91,8 +91,8 @@ def _paged_prefill_kernel(
     @pl.when(p * page_size <= last_q_pos)
     def _body():
         q = q_ref[0, 0]                               # [bs, D]
-        k = k_ref[0, :, 0, :]                         # [page, D]
-        v = v_ref[0, :, 0, :]                         # [page, D]
+        k = k_ref[0]                                  # [page, D]
+        v = v_ref[0]                                  # [page, D]
         if quantized:
             # int8 burst → upcast in VMEM after the DMA; HBM traffic is
             # the quantized bytes, the MXU computes in the query's dtype.
@@ -191,6 +191,10 @@ def paged_prefill_attention(
     assert page == page_size, (page, page_size)
     max_pages = page_table.shape[1]
     scale = scale if scale is not None else d ** -0.5
+    # merged-W view: head h of a frame is the (page, D) block at lane
+    # offset h*D (same reason as the decode kernel's view)
+    k_pool = k_pool.reshape(n_pages, page, hkv * d)
+    v_pool = v_pool.reshape(n_pages, page, hkv * d)
 
     bq = max(1, min(bq, s))
     sp = cdiv(s, bq) * bq
@@ -214,15 +218,15 @@ def paged_prefill_attention(
         # Unmapped entries (-1) clamp to frame 0; causal masking (or the
         # don't-care status of padded rows) keeps their data unused.
         frame = jnp.maximum(page_table_ref[bi, p_eff], 0)
-        return (frame, 0, h, 0)
+        return (frame, 0, h)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hkv, sp // bq, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, bs, d), lambda bi, h, qb, p, *_: (bi, h, qb, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
+            pl.BlockSpec((1, page_size, d), kv_index),
+            pl.BlockSpec((1, page_size, d), kv_index),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, bs, d), lambda bi, h, qb, p, *_: (bi, h, qb, 0)

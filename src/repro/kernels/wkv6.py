@@ -27,7 +27,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import should_interpret
-from repro.kernels import common
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
@@ -100,7 +99,7 @@ def wkv6(
             pl.BlockSpec((1, n, n), lambda b, i: (b, 0, 0)),
         ),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -31,7 +31,7 @@ import jax
 
 from repro.configs import ARCHS, get_config
 from repro.launch import hlo_analysis, hlo_cost
-from repro.launch.mesh import make_production_mesh, make_serve_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh, make_serve_mesh
 from repro.launch.specs import build_case, skip_reason
 from repro.models.config import SHAPES
 
@@ -79,7 +79,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, force: bool = False,
     mesh = _mesh_for(arch, shape, multi_pod, serve_mode)
     t0 = time.time()
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             case = build_case(arch, shape, mesh, serve_mode, variant)
             lowered = case.fn.lower(*case.args)
             t_lower = time.time() - t0

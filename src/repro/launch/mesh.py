@@ -10,35 +10,13 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX (>= 0.6) wants explicit ``axis_types``; 0.4.x has neither the
-    kwarg nor ``jax.sharding.AxisType``.  Auto axes are the 0.4.x default,
-    so falling back to the bare call is semantically identical.
-    """
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh: jax.sharding.Mesh):
-    """Context manager setting the ambient mesh across JAX versions.
-
-    ``jax.set_mesh`` (>= 0.6) or the Mesh's own context manager (0.4.x).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # 0.4.x: Mesh is its own context manager
-
-
-def _mk(shape, axes) -> jax.sharding.Mesh:
-    return compat_make_mesh(shape, axes)
+def _mk(shape, axes, devices=None) -> jax.sharding.Mesh:
+    # Auto axes: GSPMD propagates shardings; jax.make_mesh defaults to
+    # Explicit, which would make every sharding part of the array's type
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -121,6 +99,15 @@ def make_host_serve_mesh(
             break
     kv, hd = best  # (1, 1) always factors, so best is never None
     return _mk((kv, hd), ("kv", "hd"))
+
+
+def make_device_mesh(device) -> jax.sharding.Mesh:
+    """A 1x1 ('kv', 'hd') serve mesh on one given device.
+
+    Places a single-device executor on a chip of its own: the router's
+    one-chip replicas on a multi-chip host each take one of these.
+    """
+    return _mk((1, 1), ("kv", "hd"), devices=[device])
 
 
 def kv_partition_axes(

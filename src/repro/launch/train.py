@@ -13,7 +13,8 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_config
 from repro.data import DataConfig, SyntheticLMStream, make_global_batch
-from repro.launch.mesh import dp_axes, make_host_mesh, use_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import dp_axes, make_host_mesh
 from repro.launch.sharding import make_shard_hook
 from repro.models import build_model
 from repro.models.config import ShapeConfig
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--data", type=int, default=1, help="data mesh axis")
     ap.add_argument("--model", type=int, default=1, help="model mesh axis")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = make_host_mesh(args.data, args.model)
@@ -46,7 +48,7 @@ def main() -> None:
         total_steps=args.steps,
     )
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         model = build_model(cfg, remat=True, shard=make_shard_hook(mesh))
         trainer = Trainer(
             model, opt_cfg,

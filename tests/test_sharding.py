@@ -79,14 +79,14 @@ MINI_DRYRUN = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config
-    from repro.launch.mesh import compat_make_mesh, use_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.sharding import (batch_shardings, make_shard_hook,
                                        opt_shardings, param_shardings)
     from repro.models import build_model
     from repro.optim import AdamWConfig, adamw_init
     from repro.train import make_train_step
 
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(4, 2)
     cfg = get_config("{arch}", reduced=True)
     model = build_model(cfg, remat=True, shard=make_shard_hook(mesh))
     params_shape = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
@@ -97,7 +97,7 @@ MINI_DRYRUN = textwrap.dedent("""
         "labels": jax.ShapeDtypeStruct((b, s), jnp.int32),
     }}
     step = make_train_step(model, AdamWConfig(), donate=True)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(step.__wrapped__,
                      in_shardings=(param_shardings(params_shape, mesh),
                                    opt_shardings(params_shape, mesh),
@@ -135,7 +135,7 @@ ELASTIC_RESHARD = textwrap.dedent("""
     import numpy as np
     from repro import checkpoint as ckpt
     from repro.configs import get_config
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.sharding import param_shardings
     from repro.models import build_model
 
@@ -143,10 +143,7 @@ ELASTIC_RESHARD = textwrap.dedent("""
     model = build_model(cfg, remat=False)
     params = model.init(jax.random.PRNGKey(0))
 
-    def mk(shape):
-        return compat_make_mesh(shape, ("data", "model"))
-
-    mesh_a, mesh_b = mk((4, 2)), mk((2, 4))   # elastic: 4x2 -> 2x4
+    mesh_a, mesh_b = make_host_mesh(4, 2), make_host_mesh(2, 4)   # elastic: 4x2 -> 2x4
     sh_a = param_shardings(params, mesh_a)
     sh_b = param_shardings(params, mesh_b)
     p_a = jax.tree.map(jax.device_put, params, sh_a)

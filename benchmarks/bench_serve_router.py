@@ -84,7 +84,8 @@ def run() -> tuple[list[str], dict]:
             print(f"FAIL (N={n} accounting): {e}")
         total = router.global_counters()
         toks = total["decode_tokens"]
-        decode_s = sum(eng.counters.seconds("decode") for eng in engines)
+        decode_s = sum(eng.counters.seconds("serve.exec.decode")
+                       for eng in engines)
         sweep[str(n)] = dict(
             wall=wall,
             decode_tokens=int(toks),

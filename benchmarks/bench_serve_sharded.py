@@ -99,7 +99,7 @@ def run() -> tuple[list[str], dict]:
         toks = c.get("decode_tokens")
         results[name] = dict(
             wall=wall,
-            decode_tok_per_s=toks / max(c.seconds("decode"), 1e-9),
+            decode_tok_per_s=toks / max(c.seconds("serve.exec.decode"), 1e-9),
             host_syncs_per_tok=c.ratio("host_syncs", "decode_tokens"),
             ptab_syncs_per_tok=c.ratio("ptab_syncs", "decode_tokens"),
             mean_horizon=(c.get("decode_horizon")

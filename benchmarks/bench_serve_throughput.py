@@ -119,7 +119,7 @@ def run() -> tuple[list[str], dict]:
         else:
             touched = st.bytes_spilled + st.bytes_restored
             ptab_rows = c.get("ptab_rows_uploaded")
-        decode_s = c.seconds("decode") or wall
+        decode_s = c.seconds("serve.exec.decode") or wall
         results[name] = dict(
             wall=wall, tokens=sum(len(r.output) for r in done.values()),
             decode_steps=steps, decode_seconds=decode_s,
@@ -161,7 +161,7 @@ def run() -> tuple[list[str], dict]:
         toks = c.get("decode_tokens")
         sweep[label] = dict(
             decode_tokens=toks,
-            decode_tok_per_s=toks / max(c.seconds("decode"), 1e-9),
+            decode_tok_per_s=toks / max(c.seconds("serve.exec.decode"), 1e-9),
             host_syncs=c.get("host_syncs"),
             host_syncs_per_tok=c.ratio("host_syncs", "decode_tokens"),
             ptab_syncs=c.get("ptab_syncs"),

@@ -5,7 +5,7 @@ Layers (DESIGN.md §3):
   tlb             tree-PLRU TLB + shared-MMU trace simulator
   faults          precise page faults, vstart resume protocol
   context_switch  preemption spill/restore of vector state
-  counters        perf counters + snapshot FIFO
+  counters        perf counters, snapshot FIFO, host spans
   costmodel       AraOS cycle constants + TPU roofline constants
 """
 

@@ -4,8 +4,20 @@ The paper adds "a measurement infrastructure composed of performance counters
 and FIFOs to create snapshots of the internal state of the architecture and
 relevant event timestamps" (§3).  This is the software restatement: named
 monotonic counters, a bounded snapshot FIFO of (timestamp, event, payload)
-records, and context-manager timers.  Used by the serving engine, the train
-loop, and every benchmark.
+records, and host spans.  Used by the serving engine, the train loop, and
+every benchmark.
+
+A span (``PerfCounters.span``) is one named interval on the thread that
+opens it: a ``jax.profiler.TraceAnnotation``, so a profiler trace shows it
+on the same clock as the device's programs, whose host seconds are also
+summed per name (``seconds``, ``report()["timers_s"]``).  With the
+profiler off an annotation costs about a microsecond and records nothing.
+The serving path names its spans ``serve.sched.*`` (scheduler) and
+``serve.exec.*`` (executor).
+
+The scheduler stamps each request's admission (``Request.t_admit``, the
+first time it leaves the queue) and counts ``admitted`` and
+``queue_wait_us``, the summed microseconds between enqueue and admission.
 
 Counters are open-vocabulary (any name auto-registers at zero).  The radix
 prefix layer adds the reuse accounting the prefix bench gates on:
@@ -68,6 +80,8 @@ import dataclasses
 import time
 from typing import Any, Iterator
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class Snapshot:
@@ -79,7 +93,7 @@ class Snapshot:
 
 
 class PerfCounters:
-    """Named counters + bounded snapshot FIFO + wall-clock timers."""
+    """Named counters + bounded snapshot FIFO + host spans."""
 
     def __init__(self, fifo_depth: int = 4096):
         self.counters: collections.Counter[str] = collections.Counter()
@@ -128,17 +142,22 @@ class PerfCounters:
             return list(self.fifo)
         return [s for s in self.fifo if s.event == event]
 
-    # ---- timers ------------------------------------------------------------
+    # ---- spans ---------------------------------------------------------------
 
     @contextlib.contextmanager
-    def timer(self, name: str) -> Iterator[None]:
+    def span(self, name: str, **ints: int) -> Iterator[None]:
+        """Host span ``name``: a profiler annotation carrying ``ints`` as
+        its metadata (formatted only while a trace is recording), whose
+        host seconds add to ``seconds(name)``."""
         t = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name, **ints):
+                yield
         finally:
             self._timers[name] += time.perf_counter() - t
 
     def seconds(self, name: str) -> float:
+        """Host seconds summed over every ``span(name)`` closed so far."""
         return self._timers[name]
 
     # ---- reporting -----------------------------------------------------------

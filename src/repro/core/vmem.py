@@ -210,6 +210,11 @@ class VirtualMemory:
     def device_seq_lens(self) -> jnp.ndarray:
         return jnp.asarray(self._lens)
 
+    @property
+    def num_dirty_rows(self) -> int:
+        """Rows of the page table mutated since the last drain."""
+        return len(self._dirty_rows)
+
     def drain_dirty_rows(self) -> tuple[np.ndarray, np.ndarray]:
         """Rows of the page table mutated since the last drain.
 

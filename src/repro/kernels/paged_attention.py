@@ -173,6 +173,9 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        # the kernel's name in the compiled program and the device trace,
+        # which the chip benchmark matches (tests/test_chip_compile.py)
+        name="paged_decode_attention",
     )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32),
       jnp.full((1,), 1.0 if kv_scale is None else kv_scale, jnp.float32),
       q, k_pool, v_pool)

@@ -12,10 +12,10 @@ from the internal policy/data-plane types:
   :class:`ServeResult`
       What a client gets back from ``Engine.drain()`` /
       ``ReplicaRouter.drain()`` — the sampled tokens, terminal status, a
-      :class:`RequestTiming` (enqueue / first-token / last-token
-      timestamps captured at ``commit_decode``, the host-visible commit
-      point — never at detokenize, so async streaming cannot skew the SLO
-      numbers) and the request's peak page footprint.
+      :class:`RequestTiming` (enqueue / first-token / last-token /
+      admission timestamps captured by the scheduler at host-visible
+      points — never at detokenize, so async streaming cannot skew the
+      SLO numbers) and the request's peak page footprint.
 
 The internal :class:`~repro.serve.scheduler.Request` dataclass remains
 the *scheduler-plane* type (fake data planes, scheduler unit tests build
@@ -121,15 +121,18 @@ class ServeRequest:
 class RequestTiming:
     """Per-request latency stamps (``time.perf_counter`` seconds).
 
-    All three are captured by the *scheduler* at host-visible commit
-    points — ``submit`` / ``finish_prefill`` / ``commit_decode`` — never
-    by the detokenize thread, so asynchronous streaming can lag
-    arbitrarily without skewing TTFT/TPOT.
+    All four are captured by the *scheduler* at host-visible points —
+    ``submit`` / first admission out of the queue / ``finish_prefill`` /
+    ``commit_decode`` — never by the detokenize thread, so asynchronous
+    streaming can lag arbitrarily without skewing TTFT/TPOT.  ``admitted``
+    splits TTFT into queue wait (``admitted - enqueue``) and prefill; it
+    stays 0.0 for a request that was never admitted.
     """
 
     enqueue: float
     first_token: float
     last_token: float
+    admitted: float = 0.0
 
     @property
     def ttft(self) -> float:
@@ -169,7 +172,8 @@ class ServeResult:
             req_id=req.req_id, tokens=toks, status=req.status,
             timing=RequestTiming(enqueue=req.t_enqueue,
                                  first_token=req.t_first_token,
-                                 last_token=req.t_last_token),
+                                 last_token=req.t_last_token,
+                                 admitted=req.t_admit),
             pages_peak=req.pages_peak,
         )
 

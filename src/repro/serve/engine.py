@@ -31,7 +31,8 @@ Responsibilities mapped from the paper: page-table ownership and
 on-demand allocation (MMU + OS kernel); page faults during decode with
 precise accounting; preemption when the pool is exhausted (§3.1 context
 switch); scheduler quanta and tick accounting (100 Hz analogue); perf
-counters + snapshot FIFO (the paper's measurement infrastructure).
+counters and host spans on the profiler's clock (the paper's measurement
+infrastructure).
 
 **Fused multi-step decode (the amortization contract on the decode
 loop).**  AraOS's result is that VM overhead stays under 3.5% only

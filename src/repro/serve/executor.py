@@ -514,14 +514,16 @@ class Executor:
 
     def sync_page_table(self) -> None:
         """Apply host page-table deltas (dirty rows only) to the device."""
-        rows, vals = self.vmem.drain_dirty_rows()
-        if rows.size:
-            self._ptab = self._steps["ptab"](
-                self._ptab, jnp.asarray(rows), jnp.asarray(vals)
-            )
-            self.counters.inc("ptab_rows_uploaded", int(rows.size))
-            self.counters.inc("ptab_syncs")
-            self.check_sharding_invariants()
+        with self.counters.span("serve.exec.ptab_sync",
+                                rows=self.vmem.num_dirty_rows):
+            rows, vals = self.vmem.drain_dirty_rows()
+            if rows.size:
+                self._ptab = self._steps["ptab"](
+                    self._ptab, jnp.asarray(rows), jnp.asarray(vals)
+                )
+                self.counters.inc("ptab_rows_uploaded", int(rows.size))
+                self.counters.inc("ptab_syncs")
+                self.check_sharding_invariants()
 
     @property
     def device_page_table(self) -> jax.Array:
@@ -645,48 +647,60 @@ class Executor:
     def prefill(self, reqs: list[Request]) -> list[np.ndarray]:
         """Batched prefill of freshly admitted requests; returns the first
         sampled token per request (request order)."""
-        self.sync_page_table()
-        exe, bucket = self._select_aot("prefill", reqs)
-        tokens, lens, pt_rows = self._pad_prompt_batch(reqs, bucket=bucket)
-        fn = exe if exe is not None else self._steps["prefill"]
-        with self.counters.timer("prefill"):
-            logits, k, v = fn(
-                self.params, jnp.asarray(tokens),
-                jnp.asarray(lens), self.kv.k_pools, self.kv.v_pools, pt_rows,
-            )
-            # async dispatch returns immediately; block so the timer
+        span = self.counters.span
+        with span("serve.exec.prefill", rows=len(reqs)):
+            self.sync_page_table()
+            exe, bucket = self._select_aot("prefill", reqs)
+            with span("serve.exec.pack", bucket=bucket or 0):
+                tokens, lens, pt_rows = self._pad_prompt_batch(
+                    reqs, bucket=bucket)
+                tokens, lens = jnp.asarray(tokens), jnp.asarray(lens)
+            fn = exe if exe is not None else self._steps["prefill"]
+            with span("serve.exec.dispatch"):
+                logits, k, v = fn(self.params, tokens, lens,
+                                  self.kv.k_pools, self.kv.v_pools, pt_rows)
+            # async dispatch returns immediately; block so the span
             # measures execution, not dispatch
-            jax.block_until_ready(logits)
-        self.kv = self.kv._replace(k_pools=k, v_pools=v)
-        self._count_dispatch()
-        # kernel outputs must come back on the declared (replicated)
-        # layout, not whatever GSPMD inferred through the shard_map
-        self.check_sharding_invariants(
-            extra=(("prefill_logits", logits, self._rep_sh),)
-        )
-        first = self.sample(logits)
-        return [np.asarray(first[i]) for i in range(len(reqs))]
+            with span("serve.exec.wait"):
+                jax.block_until_ready(logits)
+            self.kv = self.kv._replace(k_pools=k, v_pools=v)
+            self._count_dispatch()
+            # kernel outputs must come back on the declared (replicated)
+            # layout, not whatever GSPMD inferred through the shard_map
+            self.check_sharding_invariants(
+                extra=(("prefill_logits", logits, self._rep_sh),)
+            )
+            with span("serve.exec.readback"):
+                first = self.sample(logits)
+                return [np.asarray(first[i]) for i in range(len(reqs))]
 
     def decode(self, tokens: np.ndarray, pre_lens: np.ndarray,
                active: np.ndarray) -> np.ndarray:
         """One full-slot decode step (the horizon's K=1 collapse path);
         returns sampled tokens by slot."""
-        self.sync_page_table()
-        with self.counters.timer("decode"):
-            logits, k, v = self._steps["decode"](
-                self.params, jnp.asarray(tokens),
-                self.kv.k_pools, self.kv.v_pools, self._ptab,
-                jnp.asarray(pre_lens), jnp.asarray(active),
+        span = self.counters.span
+        with span("serve.exec.decode", horizon=1):
+            self.sync_page_table()
+            with span("serve.exec.pack"):
+                tokens, pre_lens, active = (
+                    jnp.asarray(tokens), jnp.asarray(pre_lens),
+                    jnp.asarray(active))
+            with span("serve.exec.dispatch"):
+                logits, k, v = self._steps["decode"](
+                    self.params, tokens, self.kv.k_pools, self.kv.v_pools,
+                    self._ptab, pre_lens, active,
+                )
+            with span("serve.exec.wait"):
+                jax.block_until_ready(logits)
+            self.kv = self.kv._replace(k_pools=k, v_pools=v)
+            self._count_dispatch()
+            self.check_sharding_invariants(
+                extra=(("decode_logits", logits, self._rep_sh),)
             )
-            jax.block_until_ready(logits)
-        self.kv = self.kv._replace(k_pools=k, v_pools=v)
-        self._count_dispatch()
-        self.check_sharding_invariants(
-            extra=(("decode_logits", logits, self._rep_sh),)
-        )
-        self.counters.inc("decode_dispatches")
-        self.counters.inc("decode_horizon")
-        return self.sample(logits)
+            self.counters.inc("decode_dispatches")
+            self.counters.inc("decode_horizon")
+            with span("serve.exec.readback"):
+                return self.sample(logits)
 
     def decode_multi(self, plan: DecodePlan) -> np.ndarray:
         """Fused K-step decode horizon: ONE dispatch runs ``plan.horizon``
@@ -696,29 +710,35 @@ class Executor:
         run on this path.  The scheduler has already pre-faulted every page
         the horizon touches, so exactly one page-table delta sync happens
         per horizon."""
-        self.sync_page_table()
-        fused = self._decode_multi_fn(plan.horizon)
-        with self.counters.timer("decode"):
-            block, k, v, rng = fused(
-                self.params, jnp.asarray(plan.tokens),
-                self.kv.k_pools, self.kv.v_pools, self._ptab,
-                jnp.asarray(plan.pre_lens), jnp.asarray(plan.steps_left),
-                # plain float -> weak-typed scalar under jit: logits /
-                # temperature keeps the logits dtype, exactly like the
-                # host path's division by the Python float
-                self._rng, float(self.cfg.temperature),
+        span = self.counters.span
+        with span("serve.exec.decode", horizon=plan.horizon):
+            self.sync_page_table()
+            with span("serve.exec.pack"):
+                tokens, pre_lens, steps_left = (
+                    jnp.asarray(plan.tokens), jnp.asarray(plan.pre_lens),
+                    jnp.asarray(plan.steps_left))
+            with span("serve.exec.dispatch"):
+                block, k, v, rng = self._decode_multi_fn(plan.horizon)(
+                    self.params, tokens, self.kv.k_pools, self.kv.v_pools,
+                    self._ptab, pre_lens, steps_left,
+                    # plain float -> weak-typed scalar under jit: logits /
+                    # temperature keeps the logits dtype, exactly like the
+                    # host path's division by the Python float
+                    self._rng, float(self.cfg.temperature),
+                )
+            with span("serve.exec.wait"):
+                jax.block_until_ready(block)
+            self.kv = self.kv._replace(k_pools=k, v_pools=v)
+            self._rng = rng
+            self._count_dispatch()
+            self.check_sharding_invariants(
+                extra=(("decode_block", block, self._rep_sh),)
             )
-            jax.block_until_ready(block)
-        self.kv = self.kv._replace(k_pools=k, v_pools=v)
-        self._rng = rng
-        self._count_dispatch()
-        self.check_sharding_invariants(
-            extra=(("decode_block", block, self._rep_sh),)
-        )
-        self.counters.inc("host_syncs")
-        self.counters.inc("decode_dispatches")
-        self.counters.inc("decode_horizon", plan.horizon)
-        return np.asarray(block)
+            self.counters.inc("host_syncs")
+            self.counters.inc("decode_dispatches")
+            self.counters.inc("decode_horizon", plan.horizon)
+            with span("serve.exec.readback"):
+                return np.asarray(block)
 
     # ------------------------------------------------------------------
     # DataPlane protocol (driven by the Scheduler)
@@ -732,43 +752,48 @@ class Executor:
         same-step forked admissions (each request's prompt chunk starts at
         its own logical offset) — replaces both the seed's one-token-at-a-
         time teacher forcing and the per-request B=1 continuation calls."""
-        self.sync_page_table()
-        copies = [tc for tc in tail_copies if tc is not None]
-        if copies:
-            k, v = self._steps["copy_pages"](
-                self.kv.k_pools, self.kv.v_pools,
-                jnp.asarray([src for src, _ in copies]),
-                jnp.asarray([dst for _, dst in copies]),
-            )
+        span = self.counters.span
+        with span("serve.exec.continue", rows=len(reqs)):
+            self.sync_page_table()
+            copies = [tc for tc in tail_copies if tc is not None]
+            if copies:
+                with span("serve.exec.dispatch"):
+                    k, v = self._steps["copy_pages"](
+                        self.kv.k_pools, self.kv.v_pools,
+                        jnp.asarray([src for src, _ in copies]),
+                        jnp.asarray([dst for _, dst in copies]),
+                    )
+                self.kv = self.kv._replace(k_pools=k, v_pools=v)
+            exe, bucket = self._select_aot("continue", reqs)
+            with span("serve.exec.pack", bucket=bucket or 0):
+                chunks, lens, pt_rows = self._pad_prompt_batch(
+                    reqs, bucket=bucket)
+                starts = np.zeros((chunks.shape[0],), np.int32)
+                starts[: len(reqs)] = start_lens
+                args = (jnp.asarray(chunks), jnp.asarray(starts),
+                        jnp.asarray(lens))
+            fn = exe if exe is not None else self._steps["continue"]
+            with span("serve.exec.dispatch"):
+                logits, k, v = fn(self.params, *args, self.kv.k_pools,
+                                  self.kv.v_pools, pt_rows)
+            with span("serve.exec.wait"):
+                jax.block_until_ready(logits)
             self.kv = self.kv._replace(k_pools=k, v_pools=v)
-        exe, bucket = self._select_aot("continue", reqs)
-        chunks, lens, pt_rows = self._pad_prompt_batch(reqs, bucket=bucket)
-        starts = np.zeros((chunks.shape[0],), np.int32)
-        starts[: len(reqs)] = start_lens
-        fn = exe if exe is not None else self._steps["continue"]
-        with self.counters.timer("prefill"):
-            logits, k, v = fn(
-                self.params, jnp.asarray(chunks),
-                jnp.asarray(starts),
-                jnp.asarray(lens),
-                self.kv.k_pools, self.kv.v_pools, pt_rows,
+            self._count_dispatch()
+            self.check_sharding_invariants(
+                extra=(("continue_logits", logits, self._rep_sh),)
             )
-            jax.block_until_ready(logits)
-        self.kv = self.kv._replace(k_pools=k, v_pools=v)
-        self._count_dispatch()
-        self.check_sharding_invariants(
-            extra=(("continue_logits", logits, self._rep_sh),)
-        )
-        self.counters.inc("continuation_prefill_tokens", int(lens.sum()))
-        self.counters.inc(
-            "prefill_bytes_gathered",
-            self._continuation_gather_bytes(
-                [int(s) for s in start_lens], int(chunks.shape[1]),
-                len(reqs),
-            ),
-        )
-        first = self.sample(logits)
-        return [np.asarray(first[i]) for i in range(len(reqs))]
+            self.counters.inc("continuation_prefill_tokens", int(lens.sum()))
+            self.counters.inc(
+                "prefill_bytes_gathered",
+                self._continuation_gather_bytes(
+                    [int(s) for s in start_lens], int(chunks.shape[1]),
+                    len(reqs),
+                ),
+            )
+            with span("serve.exec.readback"):
+                first = self.sample(logits)
+                return [np.asarray(first[i]) for i in range(len(reqs))]
 
     def spill(self, req: Request) -> None:
         """Page-granular spill: only the victim's frames leave the device."""

@@ -250,7 +250,7 @@ class ReferenceEngine:
             self.kv.k_pools, self.kv.v_pools, pt_admit,
             jnp.zeros((len(reqs),), jnp.int32),
         )
-        with self.counters.timer("prefill"):
+        with self.counters.span("serve.exec.prefill"):
             logits, new_state = self.model.prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(lens), state
             )
@@ -328,7 +328,7 @@ class ReferenceEngine:
             self.kv.k_pools, self.kv.v_pools,
             jnp.asarray(ptab), jnp.asarray(pre_lens),
         )
-        with self.counters.timer("decode"):
+        with self.counters.span("serve.exec.decode"):
             logits, new_state = self.model.decode_step(
                 self.params, jnp.asarray(tokens), state
             )

@@ -64,10 +64,12 @@ class Request:
     #: invoked by the async detokenize thread, never by the scheduler
     stream_callback: Callable | None = None
     #: SLO timestamps (``time.perf_counter``), captured by the scheduler
-    #: at host-visible commit points — submit / first committed token /
-    #: every committed token — NEVER at detokenize, so async streaming
-    #: cannot skew TTFT/TPOT (see repro.serve.api.RequestTiming)
+    #: at host-visible points — submit / first admission out of the queue
+    #: / first committed token / every committed token — NEVER at
+    #: detokenize, so async streaming cannot skew TTFT/TPOT (see
+    #: repro.serve.api.RequestTiming)
     t_enqueue: float = 0.0
+    t_admit: float = 0.0
     t_first_token: float = 0.0
     t_last_token: float = 0.0
     #: peak mapped-page footprint over the request's lifetime
@@ -588,6 +590,17 @@ class Scheduler:
         if self.stream is not None and req.stream_callback is not None:
             self.stream.push(req, token, final)
 
+    def _stamp_admit(self, req: Request) -> None:
+        """Admission capture point: the first time ``req`` leaves the
+        queue into a mapping (plain, forked or radix-hit admission; a
+        partial-restore continuation was admitted before).  Counts
+        ``admitted`` and ``queue_wait_us`` (enqueue to admission)."""
+        if req.t_admit == 0.0:
+            req.t_admit = time.perf_counter()
+            self.counters.inc("admitted")
+            self.counters.inc("queue_wait_us",
+                              round((req.t_admit - req.t_enqueue) * 1e6))
+
     def _stamp_commit(self, req: Request, now: float) -> None:
         """Timing capture point: the host-visible commit of a sampled
         token (finish_prefill / _flush_forked / commit_decode) — NEVER
@@ -665,7 +678,6 @@ class Scheduler:
             req.t_enqueue = time.perf_counter()
         self.queue.append(req)
         self.counters.inc("submitted")
-        self.counters.snapshot("submit", req.req_id)
 
     def begin_step(self) -> None:
         self.step_i += 1
@@ -686,26 +698,36 @@ class Scheduler:
         of a router runs this independently; nothing here reads any state
         outside ``self``/the plane, which is what makes N replicas
         trivially isolated.
+
+        Each phase is a host span (``serve.sched.step`` / ``restore`` /
+        ``admit`` / ``plan`` / ``commit``, :meth:`PerfCounters.span`), and
+        the plane's calls open ``serve.exec.*`` spans inside them, so a
+        profiler trace can put each idle interval of the device down to
+        the phase the host was in.
         """
-        self.begin_step()
-        self.try_restore()
-        admitted = self.admit()
-        if admitted:
-            first = self.plane.prefill(admitted)
-            self.finish_prefill(admitted, first)
-        # plan_decode picks a fused horizon K (1 under pool pressure or
-        # pending admissions/restores) and pre-faults every page K steps
-        # will touch in one batched allocation
-        plan = self.plan_decode()
-        if plan is not None:
-            if plan.horizon > 1:
-                block = self.plane.decode_multi(plan)
-                self.commit_decode(block, horizon=plan.horizon)
-            else:
-                sampled = self.plane.decode(
-                    plan.tokens, plan.pre_lens, plan.active
-                )
-                self.commit_decode(sampled)
+        span = self.counters.span
+        with span("serve.sched.step", step=self.step_i):
+            self.begin_step()
+            with span("serve.sched.restore"):
+                self.try_restore()
+            with span("serve.sched.admit", n=len(self.queue)):
+                admitted = self.admit()
+            if admitted:
+                first = self.plane.prefill(admitted)
+                self.finish_prefill(admitted, first)
+            # plan_decode picks a fused horizon K (1 under pool pressure
+            # or pending admissions/restores) and pre-faults every page K
+            # steps will touch in one batched allocation
+            plan = self.plan_decode()
+            if plan is not None:
+                if plan.horizon > 1:
+                    block = self.plane.decode_multi(plan)
+                    self.commit_decode(block, horizon=plan.horizon)
+                else:
+                    sampled = self.plane.decode(
+                        plan.tokens, plan.pre_lens, plan.active
+                    )
+                    self.commit_decode(sampled)
 
     # ------------------------------------------------------------------
     # reach checks (livelock prevention)
@@ -774,7 +796,7 @@ class Scheduler:
         return (lifetime > self.vmem.config.max_tokens_per_seq
                 or own > self.attainable_pages())
 
-    def _fail(self, req: Request, reason: str) -> None:
+    def _fail(self, req: Request) -> None:
         """Terminal parking for a request that can never fit (reach check):
         surfaced through ``done`` with status ``failed`` so callers see it
         and ``run()`` terminates instead of spinning until ``max_steps``."""
@@ -784,7 +806,6 @@ class Scheduler:
             req.t_first_token = req.t_last_token
         self.done[req.req_id] = req
         self.counters.inc("failed_unreachable")
-        self.counters.snapshot("failed_" + reason, req.req_id)
         # streams always terminate: a failed request still gets a final
         # event (token=None) so a client waiting on `final` never hangs
         self._emit(req, None, final=True)
@@ -830,7 +851,6 @@ class Scheduler:
         self.running[req_id] = req
         self.slot_of[req_id] = self.vmem.seq(req_id).slot
         self.counters.inc("restores")
-        self.counters.snapshot("restore", req_id)
 
     def try_restore(self) -> list[Request]:
         restored: list[Request] = []
@@ -856,7 +876,7 @@ class Scheduler:
                 self.state.restore_blocked.pop(req_id, None)
                 req = self._swap_requests.pop(req_id)
                 self.plane.discard(req)    # free the host-side swap record
-                self._fail(req, "restore")
+                self._fail(req)
                 continue
             if len(self.running) >= self.cfg.max_batch:
                 break
@@ -886,7 +906,6 @@ class Scheduler:
                 # the victims queued BEHIND it a bounded second chance,
                 # or a head pinned to a failing plane starves the queue.
                 self.counters.inc("restore_failures")
-                self.counters.snapshot("restore_failure", req_id)
                 self._second_chance_scan(restored)
                 break
             self.swapped.popleft()
@@ -918,7 +937,6 @@ class Scheduler:
                                    shared_pages=shared or None)
             except RestoreFailure:
                 self.counters.inc("restore_failures")
-                self.counters.snapshot("restore_failure", req_id)
                 i += 1
                 continue
             del self.swapped[i]
@@ -974,7 +992,6 @@ class Scheduler:
             self.plane.restore(req, keep, shared_pages=shared or None)
         except RestoreFailure:
             self.counters.inc("restore_failures")
-            self.counters.snapshot("restore_failure", req_id)
             return False
         # full committed content, for re-registering the restored run
         # with the radix cache at resume time (best effort: a fork's
@@ -1002,7 +1019,6 @@ class Scheduler:
         self.state.partial_resume[req_id] = (keep, tail, reg)
         self.queue.appendleft(req)     # keeps the victim's FIFO priority
         self.counters.inc("partial_restores")
-        self.counters.snapshot("partial_restore", (req_id, keep))
         return True
 
     # ------------------------------------------------------------------
@@ -1109,7 +1125,6 @@ class Scheduler:
         del self.running[victim.req_id]
         del self.slot_of[victim.req_id]
         self.counters.inc("preemptions")
-        self.counters.snapshot("preempt", victim.req_id)
 
     # ------------------------------------------------------------------
     # admission
@@ -1188,7 +1203,7 @@ class Scheduler:
             matched, owner = self.probe_prefix(req)
             if self._admission_unreachable(req, matched, owner):
                 self.queue.popleft()
-                self._fail(req, "admit")
+                self._fail(req)
                 continue
             if matched:
                 # the matched whole pages arrive by refcount, not
@@ -1211,12 +1226,14 @@ class Scheduler:
                     break
                 pending.append(entry)
                 self.queue.popleft()
+                self._stamp_admit(req)
                 continue
             if matched:
                 entry = self._radix_bookkeeping(req, matched, owner)
                 if entry is not None:
                     pending.append(entry)
                     self.queue.popleft()
+                    self._stamp_admit(req)
                     continue
                 # hit could not be honored (owner raced away / pool
                 # exhausted mid-fork): fall through to cold admission
@@ -1225,6 +1242,7 @@ class Scheduler:
             except OutOfPagesError:
                 break
             self.queue.popleft()
+            self._stamp_admit(req)
             admitted.append(req)
         self._flush_forked(pending)
         return admitted
@@ -1320,7 +1338,6 @@ class Scheduler:
         self.counters.inc("prefix_hits")
         self.counters.inc("pages_reused", matched // self.cfg.page_size)
         self.counters.inc("prefill_tokens_skipped", matched)
-        self.counters.snapshot("prefix_hit", (req.req_id, matched))
         return (req, matched, None, full, None)
 
     def _flush_forked(
@@ -1347,45 +1364,47 @@ class Scheduler:
         firsts = self.plane.admit_forked_batch(
             reqs, [e[1] for e in pending], [e[2] for e in pending]
         )
-        now = time.perf_counter()
-        for (req, start_len, _, reg, orig), first in zip(pending, firsts):
-            if orig is not None:
-                req = orig                  # commit the REAL request;
+        with self.counters.span("serve.sched.commit", n=len(pending)):
+            now = time.perf_counter()
+            for (req, start_len, _, reg, orig), first in zip(pending,
+                                                             firsts):
+                if orig is not None:
+                    req = orig              # commit the REAL request;
                                             # `first` is discarded (above)
-            else:
-                req.prefix_len = start_len
-                req.output.append(first)
-            req.status = "running"
-            self.running[req.req_id] = req
-            self.slot_of[req.req_id] = self.vmem.seq(req.req_id).slot
-            if reg is not None and self.prefix_cache is not None:
-                self.prefix_cache.register(req.req_id, reg)
-            self._stamp_commit(req, now)
-            if orig is None:
-                self._emit(req, req.output[-1], final=False)
-        self.counters.inc("fork_batches")
-        pending.clear()
+                else:
+                    req.prefix_len = start_len
+                    req.output.append(first)
+                req.status = "running"
+                self.running[req.req_id] = req
+                self.slot_of[req.req_id] = self.vmem.seq(req.req_id).slot
+                if reg is not None and self.prefix_cache is not None:
+                    self.prefix_cache.register(req.req_id, reg)
+                self._stamp_commit(req, now)
+                if orig is None:
+                    self._emit(req, req.output[-1], final=False)
+            self.counters.inc("fork_batches")
+            pending.clear()
 
     def finish_prefill(self, reqs: list[Request], first_tokens: Any) -> None:
         """Commit a plain-prefill batch: mark running, record accounting.
         The prompts enter the radix cache here — the plane call that
         committed their KV has completed."""
-        now = time.perf_counter()
-        for i, r in enumerate(reqs):
-            r.status = "running"
-            r.output.append(np.asarray(first_tokens[i]))
-            self.running[r.req_id] = r
-            self.slot_of[r.req_id] = self.vmem.seq(r.req_id).slot
-            if self.prefix_cache is not None:
-                self.prefix_cache.register(r.req_id, r.prompt)
-            self._stamp_commit(r, now)
-            self._emit(r, r.output[-1], final=False)
-        lens = [len(r.prompt) for r in reqs]
-        self.counters.inc("prefill_tokens", int(sum(lens)))
-        self.counters.inc("prefill_translation_bursts", int(
-            sum(self.vmem.config.pages_for(int(x)) for x in lens)
-        ))
-        self.counters.snapshot("prefill", [r.req_id for r in reqs])
+        with self.counters.span("serve.sched.commit", n=len(reqs)):
+            now = time.perf_counter()
+            for i, r in enumerate(reqs):
+                r.status = "running"
+                r.output.append(np.asarray(first_tokens[i]))
+                self.running[r.req_id] = r
+                self.slot_of[r.req_id] = self.vmem.seq(r.req_id).slot
+                if self.prefix_cache is not None:
+                    self.prefix_cache.register(r.req_id, r.prompt)
+                self._stamp_commit(r, now)
+                self._emit(r, r.output[-1], final=False)
+            lens = [len(r.prompt) for r in reqs]
+            self.counters.inc("prefill_tokens", int(sum(lens)))
+            self.counters.inc("prefill_translation_bursts", int(
+                sum(self.vmem.config.pages_for(int(x)) for x in lens)
+            ))
 
     # ------------------------------------------------------------------
     # decode planning
@@ -1491,8 +1510,9 @@ class Scheduler:
     def plan_decode(self) -> DecodePlan | None:
         """One call per engine step: pick the horizon, fault in every page
         it needs, and build the decode plan (what ``Engine.step`` drives)."""
-        k = self.grow_horizon(self.plan_horizon())
-        return self.decode_plan(k)
+        want = self.plan_horizon()
+        with self.counters.span("serve.sched.plan", horizon=want):
+            return self.decode_plan(self.grow_horizon(want))
 
     def decode_plan(self, horizon: int = 1) -> DecodePlan | None:
         if not self.running:
@@ -1524,35 +1544,36 @@ class Scheduler:
         exactly.  A lane stops consuming the block the moment it retires;
         later block rows for that slot are device scratch output.
         """
-        block = sampled if horizon > 1 else [sampled]
-        for t in range(horizon):
-            if not self.running:
-                break
-            if t:
-                # the fused dispatch compressed K token-steps into one
-                # engine step; advance the scheduler's logical clock per
-                # inner step so step_i, the 100 Hz tick accounting and
-                # run() budgets stay in TOKEN-steps — identical to a K=1
-                # run of the same workload
-                self.begin_step()
-            self.counters.inc("decode_tokens", len(self.running))
-            self.counters.inc("decode_translations", len(self.running))
-            now = time.perf_counter()
-            for req_id in list(self.running):
-                r = self.running[req_id]
-                slot = self.slot_of[req_id]
-                r.output.append(np.asarray(block[t][slot]))
-                # SLO timing capture point: the host-visible commit of
-                # this token — stamped BEFORE the async stream push, so
-                # detokenize lag cannot skew TTFT/TPOT
-                self._stamp_commit(r, now)
-                retired = len(r.output) >= r.max_new_tokens
-                if retired:
-                    r.status = "done"
-                    self.done[req_id] = r
-                    del self.running[req_id]
-                    del self.slot_of[req_id]
-                    self.vmem.unmap_seq(req_id)
-                    self.counters.inc("completed")
-                    self.counters.snapshot("done", req_id)
-                self._emit(r, r.output[-1], final=retired)
+        with self.counters.span("serve.sched.commit",
+                                n=len(self.running)):
+            block = sampled if horizon > 1 else [sampled]
+            for t in range(horizon):
+                if not self.running:
+                    break
+                if t:
+                    # the fused dispatch compressed K token-steps into one
+                    # engine step; advance the scheduler's logical clock per
+                    # inner step so step_i, the 100 Hz tick accounting and
+                    # run() budgets stay in TOKEN-steps — identical to a K=1
+                    # run of the same workload
+                    self.begin_step()
+                self.counters.inc("decode_tokens", len(self.running))
+                self.counters.inc("decode_translations", len(self.running))
+                now = time.perf_counter()
+                for req_id in list(self.running):
+                    r = self.running[req_id]
+                    slot = self.slot_of[req_id]
+                    r.output.append(np.asarray(block[t][slot]))
+                    # SLO timing capture point: the host-visible commit of
+                    # this token — stamped BEFORE the async stream push, so
+                    # detokenize lag cannot skew TTFT/TPOT
+                    self._stamp_commit(r, now)
+                    retired = len(r.output) >= r.max_new_tokens
+                    if retired:
+                        r.status = "done"
+                        self.done[req_id] = r
+                        del self.running[req_id]
+                        del self.slot_of[req_id]
+                        self.vmem.unmap_seq(req_id)
+                        self.counters.inc("completed")
+                    self._emit(r, r.output[-1], final=retired)

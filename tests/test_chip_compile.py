@@ -4,7 +4,9 @@ Nothing runs: each test lowers a Pallas kernel with ``interpret=False`` at
 qwen2-7b widths (Hkv 4, G 7, head_dim 128, page 16, W = Hkv*D = 512)
 against one chip of a described ``v5e:2x2`` topology and compiles it with
 the TPU compiler, which refuses what interpret mode accepts: block shapes
-off the (8, 128) tiling, and more VMEM than a kernel may use.
+off the (8, 128) tiling, and more VMEM than a kernel may use.  The
+executor's prefill and decode steps are compiled whole too, to pin the
+program and kernel names that the chip benchmark finds in a device trace.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -19,6 +21,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import flash_attention as flash_module
+from repro.kernels import paged_attention as paged_module
+from repro.kernels import paged_copy as copy_module
 from repro.kernels.common import round_up
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_decode_attention
@@ -117,3 +122,56 @@ def test_flash_attention_compiles(one_chip, seq):
         _sds(one_chip, (1, HKV, sk, D), jnp.bfloat16),
         _sds(one_chip, (1, HKV, sk, D), jnp.bfloat16),
     )
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode", "decode_multi"])
+def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
+    """The executor's jitted steps, kernels on, at qwen2-7b attention
+    widths (one layer, narrow MLP and vocabulary): the compiled program
+    keeps the names ``chipbench/reads.py`` and the roofline readers match
+    in the device trace, so a rename fails here and not as a silent
+    ``null`` per-layer metric on the chip."""
+    from repro.models import build_model
+    from repro.models.config import ModelConfig
+    from repro.serve import executor as ex
+
+    # the kernels pick interpret mode off the TPU backend; compile them
+    for mod in (paged_module, flash_module, copy_module):
+        monkeypatch.setattr(mod, "should_interpret", lambda: False)
+    model = build_model(ModelConfig(
+        name="names", family="dense", num_layers=1, d_model=512,
+        num_heads=HKV * G, num_kv_heads=HKV, d_ff=512, vocab_size=1024,
+        head_dim=D, qkv_bias=True, param_dtype="bfloat16"),
+        remat=False, use_kernels=True)
+
+    def sds(shape, dtype):
+        return _sds(one_chip, shape, dtype)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+    kv = jax.eval_shape(lambda: model.init_kv_state(BATCH, 48, PAGE, 6))
+    pool = sds(kv.k_pools.shape, kv.k_pools.dtype)
+    rows = sds((BATCH,), jnp.int32)
+    table = sds((BATCH, 6), jnp.int32)
+    if step == "prefill":
+        lowered = ex._prefill_step.lower(
+            model, params, sds((BATCH, 96), jnp.int32), rows, pool, pool,
+            table)
+        names = ("_prefill_impl", "flash_attention")
+    elif step == "decode":
+        lowered = ex._decode_step.lower(
+            model, params, rows, pool, pool, table, rows,
+            sds((BATCH,), jnp.bool_))
+        names = ("_decode_impl", "paged_decode_attention")
+    else:
+        lowered = ex._decode_multi_step.lower(
+            model, params, rows, pool, pool, table, rows, rows,
+            sds((2,), jnp.uint32), 1.0, 2, True)
+        names = ("_decode_multi_impl", "paged_decode_attention")
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule jit_{names[0]},")
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert any(line.lstrip().startswith(f"%{names[1]}.")
+               for line in kernels), kernels[:3]

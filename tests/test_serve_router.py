@@ -373,10 +373,11 @@ class TestN1Equivalence:
         assert list(sched.done) == list(router.done)   # completion ORDER
         assert sched.step_i == rsched.step_i
         # identical per-replica counters modulo the router's own placement
-        # bookkeeping
+        # bookkeeping and the one wall-clock sum (queue wait, in us)
         a = dict(sched.counters.counters)
         b = dict(rsched.counters.counters)
         b.pop("router_placements")
+        assert a.pop("queue_wait_us") >= 0 and b.pop("queue_wait_us") >= 0
         assert a == b
         # the fake planes saw the identical call sequence
         assert plane.events == planes[0].events

@@ -46,9 +46,12 @@ observability the open-loop SLO gate runs on: ``aot_hits`` (prefill /
 continuation batches dispatched through an executable compiled at engine
 build), ``aot_misses`` (batches that fell back to the shape-keyed jit —
 the gate requires 0 after warmup, because each miss is a potential
-first-hit compile stall on the serving path), and ``bucket_pad_tokens``
+first-hit compile stall on the serving path), ``bucket_pad_tokens``
 (pure padding overhead of rounding batches up to the compiled shape —
-gated per prefill token, "le").  The async stream pipeline adds
+gated per prefill token, "le"), and ``prefill_pad_rows`` (the pad rows
+alone: rows an AOT prefill or continuation dispatched beyond the
+admitted ones — plain prefill rounds up to a rung of the row ladder,
+continuation to ``max_batch``).  The async stream pipeline adds
 ``detok_backlog_peak``: the deepest the background detokenize queue ever
 got — a PEAK, not a monotonic count, written directly by the
 detokenizer — the observable for "host post-processing is falling behind

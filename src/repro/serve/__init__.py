@@ -61,8 +61,11 @@ Split per the AraOS architecture, one layer per plane:
   Engine/Router is a hard ``TypeError`` (the one-PR deprecation shim is
   gone).  With ``ServeConfig.aot_buckets`` the Executor
   pre-compiles bucketed prefill/continuation executables at build time so
-  no request pays a first-hit jit stall (``aot_hits``/``aot_misses``/
-  ``bucket_pad_tokens``; the open-loop SLO gate in
+  no request pays a first-hit jit stall, with plain prefill compiled per
+  rung of a row ladder (``executor.row_buckets``) so an admission of n
+  rows pads to the smallest rung that holds it, not to ``max_batch``
+  (``aot_hits``/``aot_misses``/``bucket_pad_tokens``/
+  ``prefill_pad_rows``; the open-loop SLO gate in
   ``benchmarks/bench_serve_slo.py`` holds ``aot_misses == 0``).
 
 :class:`ReferenceEngine` is the frozen pre-split seed implementation kept

@@ -166,8 +166,9 @@ _copy_pages = jax.jit(_copy_pages_impl, donate_argnums=(0, 1))
 def select_bucket(n: int, buckets: tuple[int, ...] | None) -> int | None:
     """Smallest AOT bucket that can hold an ``n``-token (burst-aligned)
     prompt batch, or ``None`` on a miss — the caller then falls back to
-    the shape-keyed jit path.  ``buckets`` is the sorted tuple
-    ``ServeConfig.aot_buckets`` normalized to."""
+    the shape-keyed jit path.  ``buckets`` is a sorted tuple: the
+    ``ServeConfig.aot_buckets`` lengths, or a :func:`row_buckets` ladder
+    for an ``n``-row admission."""
     if not buckets:
         return None
     for b in buckets:
@@ -176,8 +177,22 @@ def select_bucket(n: int, buckets: tuple[int, ...] | None) -> int | None:
     return None
 
 
+def row_buckets(max_batch: int) -> tuple[int, ...]:
+    """Row counts the AOT prefill programs are compiled for: the powers
+    of 4 below ``max_batch``, then ``max_batch`` itself — ``(1, 4, 16)``
+    for 16 lanes, ``(1, 4, 16, 64)`` for 64.  An admission of ``n`` rows
+    runs the smallest that holds it, so a lone arrival is a one-row
+    program, not a ``max_batch``-row one.  Powers of 4 rather than 2:
+    each rung adds one compiled program per length bucket to build."""
+    rows, r = [], 1
+    while r < max_batch:
+        rows.append(r)
+        r *= 4
+    return (*rows, max_batch)
+
+
 #: AOT-compiled prefill/continuation executables, keyed by
-#: (step-model twin, mesh, kind, bucket, batch/pool geometry).  Module
+#: (step-model twin, mesh, kind, rows, bucket, batch/pool geometry).  Module
 #: level — mirroring the lru_cached model twins above — so every engine
 #: over the same (model twin, mesh, geometry) binds the SAME compiled
 #: executable instead of re-lowering at each build.
@@ -392,10 +407,15 @@ class Executor:
                 "decode": functools.partial(_decode_step, self._step_model),
                 "copy_pages": _copy_pages,
             }
+        #: row counts each AOT kind is compiled for: plain prefill takes
+        #: the :func:`row_buckets` ladder; continuation keeps ``max_batch``
+        self._aot_rows = {"prefill": row_buckets(cfg.max_batch),
+                          "continue": (cfg.max_batch,)}
         #: AOT-bucketed prefill/continuation executables for THIS engine,
-        #: (kind, bucket) -> compiled; populated at build so no request
-        #: ever pays a first-hit jit stall (``ServeConfig.aot_buckets``)
-        self._aot: dict[tuple[str, int], Any] = {}
+        #: (kind, rows, bucket) -> compiled; populated at build so no
+        #: request ever pays a first-hit jit stall
+        #: (``ServeConfig.aot_buckets``)
+        self._aot: dict[tuple[str, int, int], Any] = {}
         if getattr(cfg, "aot_buckets", None):
             self._compile_aot()
 
@@ -403,78 +423,85 @@ class Executor:
     # AOT-bucketed prefill (ServeConfig.aot_buckets)
     # ------------------------------------------------------------------
 
-    def _aot_key(self, kind: str, bucket: int) -> tuple:
+    def _aot_key(self, kind: str, rows: int, bucket: int) -> tuple:
         """Module-cache key: everything the compiled executable's shapes,
         dtypes and shardings derive from.  The kv-dtype / ref-path / mesh
         twins are all folded into ``self._step_model`` + ``self.mesh``, so
         distinct twins get distinct executables and identical twins share."""
-        return (self._step_model, self.mesh, kind, bucket,
+        return (self._step_model, self.mesh, kind, rows, bucket,
                 self.cfg.max_batch, self.cfg.num_pages,
                 self.cfg.page_size, self.cfg.max_pages_per_seq)
 
-    def _aot_operands(self, kind: str, bucket: int) -> tuple:
-        """``ShapeDtypeStruct`` operands of one bucketed dispatch: full
-        ``max_batch`` rows, ``bucket``-length prompts, the executor's live
-        pool/page-table geometry (quantized pools keep their narrow dtype
-        because the SDS is read off the allocated pools)."""
+    def _aot_operands(self, kind: str, bucket: int, rows: int) -> tuple:
+        """``ShapeDtypeStruct`` operands of one bucketed dispatch: ``rows``
+        rows (a rung of the prefill row ladder, ``max_batch`` for a
+        continuation) of ``bucket``-length prompts, the executor's live
+        pool geometry (quantized pools keep their narrow dtype because the
+        SDS is read off the allocated pools) and ``rows`` page-table
+        rows."""
         sds = jax.ShapeDtypeStruct
-        b = self.cfg.max_batch
         p_sds = jax.tree_util.tree_map(
             lambda a: sds(jnp.shape(a), a.dtype), self.params
         )
-        tok = sds((b, bucket), jnp.int32)
-        lens = sds((b,), jnp.int32)
+        tok = sds((rows, bucket), jnp.int32)
+        lens = sds((rows,), jnp.int32)
         k = sds(self.kv.k_pools.shape, self.kv.k_pools.dtype)
         v = sds(self.kv.v_pools.shape, self.kv.v_pools.dtype)
-        pt = sds((b, self.cfg.max_pages_per_seq), jnp.int32)
+        pt = sds((rows, self.cfg.max_pages_per_seq), jnp.int32)
         if kind == "continue":
-            starts = sds((b,), jnp.int32)
+            starts = sds((rows,), jnp.int32)
             return (p_sds, tok, starts, lens, k, v, pt)
         return (p_sds, tok, lens, k, v, pt)
 
     def _compile_aot(self) -> None:
-        """Pre-lower and ``aot_compile`` every (kind, bucket) executable
-        at engine build.  Single-device lowering goes through the module
-        jits (the model is a static argument, baked in at lower time);
-        mesh lowering goes through the per-(model, mesh) sharded steps so
-        the executables carry the declared in/out shardings."""
-        for kind in ("prefill", "continue"):
-            for bucket in self.cfg.aot_buckets:
-                key = self._aot_key(kind, bucket)
-                exe = _AOT_CACHE.get(key)
-                if exe is None:
-                    ops = self._aot_operands(kind, bucket)
-                    if self.mesh is not None:
-                        exe = self._steps[kind].lower(*ops).compile()
-                    elif kind == "prefill":
-                        exe = _prefill_step.lower(
-                            self._step_model, *ops).compile()
-                    else:
-                        exe = _continue_step.lower(
-                            self._step_model, *ops).compile()
-                    _AOT_CACHE[key] = exe
-                self._aot[(kind, bucket)] = exe
+        """Pre-lower and ``aot_compile`` every (kind, rows, bucket)
+        executable at engine build.  Single-device lowering goes through
+        the module jits (the model is a static argument, baked in at lower
+        time); mesh lowering goes through the per-(model, mesh) sharded
+        steps so the executables carry the declared in/out shardings at
+        every row count."""
+        for kind, ladder in self._aot_rows.items():
+            for rows in ladder:
+                for bucket in self.cfg.aot_buckets:
+                    key = self._aot_key(kind, rows, bucket)
+                    exe = _AOT_CACHE.get(key)
+                    if exe is None:
+                        ops = self._aot_operands(kind, bucket, rows)
+                        if self.mesh is not None:
+                            exe = self._steps[kind].lower(*ops).compile()
+                        elif kind == "prefill":
+                            exe = _prefill_step.lower(
+                                self._step_model, *ops).compile()
+                        else:
+                            exe = _continue_step.lower(
+                                self._step_model, *ops).compile()
+                        _AOT_CACHE[key] = exe
+                    self._aot[(kind, rows, bucket)] = exe
 
     def _select_aot(self, kind: str, reqs: list[Request]):
-        """The AOT executable for this batch — ``(compiled, bucket)``, or
-        ``(None, None)`` to fall back to the shape-keyed jit.  Hits and
-        misses are counted only when bucketing is configured; a miss is a
-        batch whose burst-aligned width exceeds every bucket (or a non-1D
-        prompt modality the buckets were not compiled for)."""
+        """The AOT executable for this batch — ``(compiled, rows,
+        bucket)``: the smallest compiled row count that holds the admitted
+        rows and the smallest bucket that holds their burst-aligned width;
+        ``(None, len(reqs), None)`` to fall back to the shape-keyed jit.
+        Hits and misses are counted only when bucketing is configured; a
+        miss is a batch whose burst-aligned width exceeds every bucket (or
+        a non-1D prompt modality the buckets were not compiled for)."""
+        nrows = len(reqs)
         if not self._aot:
-            return None, None
+            return None, nrows, None
         page = self.cfg.page_size
         smax = max(len(r.prompt) for r in reqs)
         smax = -(-smax // page) * page
         bucket = None
         if not reqs[0].prompt.shape[1:]:     # 1-D token prompts only
             bucket = select_bucket(smax, self.cfg.aot_buckets)
-        exe = self._aot.get((kind, bucket)) if bucket is not None else None
+        rows = select_bucket(nrows, self._aot_rows[kind])
+        exe = self._aot.get((kind, rows, bucket))
         if exe is None:
             self.counters.inc("aot_misses")
-            return None, None
+            return None, nrows, None
         self.counters.inc("aot_hits")
-        return exe, bucket
+        return exe, rows, bucket
 
     # ------------------------------------------------------------------
     # sharding invariants (mesh mode)
@@ -604,33 +631,33 @@ class Executor:
         self._count_dispatch()
         self.counters.inc("prefix_tokens", n)
 
-    def _pad_prompt_batch(self, reqs: list[Request],
-                          bucket: int | None = None):
+    def _pad_prompt_batch(self, reqs: list[Request], bucket: int | None,
+                          rows: int):
         """Burst-aligned ``[B, smax]`` prompt matrix + true lengths + the
         batch's page-table rows — shared by plain and forked admission so
         padding/slot-lookup policy cannot desynchronize between them.
 
-        With ``bucket`` (an AOT dispatch) the batch is padded to the
-        compiled shape — ``max_batch`` rows of ``bucket`` tokens.  The
+        ``bucket`` and ``rows`` are what ``_select_aot`` picked.  With
+        ``bucket`` (an AOT dispatch) the batch is padded to the compiled
+        shape — ``rows`` rows (a rung of the row ladder) of ``bucket``
+        tokens; without it ``rows`` is the admitted row count.  The
         padding is numerically inert: pad rows carry ``lens=0`` and
         all-INVALID_PAGE table rows (writes route to the scratch frame),
         pad columns sit beyond every real row's length so causal masking
         excludes them — real-row outputs are bit-identical to the
         unbucketed dispatch.  The pure overhead (padded cells minus what
         the shape-keyed dispatch would have carried) is counted as
-        ``bucket_pad_tokens``."""
+        ``bucket_pad_tokens``, the pad rows alone as
+        ``prefill_pad_rows``."""
         page = self.cfg.page_size
         smax = max(len(r.prompt) for r in reqs)
         smax = -(-smax // page) * page            # burst-align (jit reuse)
         nrows = len(reqs)
-        rows = nrows
         if bucket is not None:
-            self.counters.inc(
-                "bucket_pad_tokens",
-                self.cfg.max_batch * bucket - nrows * smax,
-            )
+            self.counters.inc("bucket_pad_tokens",
+                              rows * bucket - nrows * smax)
+            self.counters.inc("prefill_pad_rows", rows - nrows)
             smax = bucket
-            rows = self.cfg.max_batch
         tok_shape = (rows, smax) + reqs[0].prompt.shape[1:]
         tokens = np.zeros(tok_shape, np.int32)
         for i, r in enumerate(reqs):
@@ -650,10 +677,10 @@ class Executor:
         span = self.counters.span
         with span("serve.exec.prefill", rows=len(reqs)):
             self.sync_page_table()
-            exe, bucket = self._select_aot("prefill", reqs)
-            with span("serve.exec.pack", bucket=bucket or 0):
+            exe, rows, bucket = self._select_aot("prefill", reqs)
+            with span("serve.exec.pack", bucket=bucket or 0, rows=rows):
                 tokens, lens, pt_rows = self._pad_prompt_batch(
-                    reqs, bucket=bucket)
+                    reqs, bucket, rows)
                 tokens, lens = jnp.asarray(tokens), jnp.asarray(lens)
             fn = exe if exe is not None else self._steps["prefill"]
             with span("serve.exec.dispatch"):
@@ -764,10 +791,10 @@ class Executor:
                         jnp.asarray([dst for _, dst in copies]),
                     )
                 self.kv = self.kv._replace(k_pools=k, v_pools=v)
-            exe, bucket = self._select_aot("continue", reqs)
-            with span("serve.exec.pack", bucket=bucket or 0):
+            exe, rows, bucket = self._select_aot("continue", reqs)
+            with span("serve.exec.pack", bucket=bucket or 0, rows=rows):
                 chunks, lens, pt_rows = self._pad_prompt_batch(
-                    reqs, bucket=bucket)
+                    reqs, bucket, rows)
                 starts = np.zeros((chunks.shape[0],), np.int32)
                 starts[: len(reqs)] = start_lens
                 args = (jnp.asarray(chunks), jnp.asarray(starts),

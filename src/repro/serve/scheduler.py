@@ -139,13 +139,18 @@ class ServeConfig:
     #: of these lengths and dispatched through executables pre-lowered and
     #: compiled at engine build (``aot_compile`` against
     #: ``ShapeDtypeStruct``s), so no request pays a first-hit jit stall.
-    #: Buckets must be positive ``page_size`` multiples within the
-    #: page-table reach; ``None`` (default) keeps the plain shape-keyed
-    #: jit path.  Padding is numerically inert — pad rows carry lens=0 and
-    #: INVALID_PAGE table rows (routed to the scratch/trash frame) and
-    #: causal masking keeps pad columns out of every real row — so greedy
-    #: streams are bit-identical to the unbucketed dispatch.  Counters:
-    #: ``aot_hits`` / ``aot_misses`` / ``bucket_pad_tokens``.
+    #: Rows follow a ladder derived from ``max_batch``
+    #: (``executor.row_buckets``: the powers of 4 below it, then
+    #: ``max_batch``): an admission of n rows runs the smallest compiled
+    #: row count that holds n, so a lone arrival is a one-row program.
+    #: Continuation prefill keeps ``max_batch`` rows.  Buckets must be
+    #: positive ``page_size`` multiples within the page-table reach;
+    #: ``None`` (default) keeps the plain shape-keyed jit path.  Padding
+    #: is numerically inert — pad rows carry lens=0 and INVALID_PAGE
+    #: table rows (routed to the scratch/trash frame) and causal masking
+    #: keeps pad columns out of every real row — so greedy streams are
+    #: bit-identical to the unbucketed dispatch.  Counters: ``aot_hits``
+    #: / ``aot_misses`` / ``bucket_pad_tokens`` / ``prefill_pad_rows``.
     aot_buckets: tuple[int, ...] | None = None
     #: serve-mesh request: "off" (single device), "auto" (factor all
     #: visible devices over ('kv','hd')), or an integer device count.

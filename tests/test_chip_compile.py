@@ -124,16 +124,12 @@ def test_flash_attention_compiles(one_chip, seq):
     )
 
 
-@pytest.mark.parametrize("step", ["prefill", "decode", "decode_multi"])
-def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
-    """The executor's jitted steps, kernels on, at qwen2-7b attention
-    widths (one layer, narrow MLP and vocabulary): the compiled program
-    keeps the names ``chipbench/reads.py`` and the roofline readers match
-    in the device trace, so a rename fails here and not as a silent
-    ``null`` per-layer metric on the chip."""
+def _serve_step_operands(one_chip, monkeypatch):
+    """A kernel model at qwen2-7b attention widths (one layer, narrow MLP
+    and vocabulary), kernels compiled rather than interpreted, with its
+    parameters and KV pool as shapes on the described chip."""
     from repro.models import build_model
     from repro.models.config import ModelConfig
-    from repro.serve import executor as ex
 
     # the kernels pick interpret mode off the TPU backend; compile them
     for mod in (paged_module, flash_module, copy_module):
@@ -143,15 +139,28 @@ def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
         num_heads=HKV * G, num_kv_heads=HKV, d_ff=512, vocab_size=1024,
         head_dim=D, qkv_bias=True, param_dtype="bfloat16"),
         remat=False, use_kernels=True)
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+    kv = jax.eval_shape(lambda: model.init_kv_state(BATCH, 48, PAGE, 6))
+    pool = _sds(one_chip, kv.k_pools.shape, kv.k_pools.dtype)
+    return model, params, pool
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode", "decode_multi"])
+def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
+    """The executor's jitted steps, kernels on, at qwen2-7b attention
+    widths (one layer, narrow MLP and vocabulary): the compiled program
+    keeps the names ``chipbench/reads.py`` and the roofline readers match
+    in the device trace, so a rename fails here and not as a silent
+    ``null`` per-layer metric on the chip."""
+    from repro.serve import executor as ex
+
+    model, params, pool = _serve_step_operands(one_chip, monkeypatch)
 
     def sds(shape, dtype):
         return _sds(one_chip, shape, dtype)
 
-    params = jax.tree_util.tree_map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
-    kv = jax.eval_shape(lambda: model.init_kv_state(BATCH, 48, PAGE, 6))
-    pool = sds(kv.k_pools.shape, kv.k_pools.dtype)
     rows = sds((BATCH,), jnp.int32)
     table = sds((BATCH, 6), jnp.int32)
     if step == "prefill":
@@ -175,3 +184,21 @@ def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
                if "tpu_custom_call" in line and " = " in line]
     assert any(line.lstrip().startswith(f"%{names[1]}.")
                for line in kernels), kernels[:3]
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_prefill_compiles_on_each_rung(one_chip, monkeypatch, rows):
+    """The AOT prefill runs on the low rungs of the row ladder
+    (``executor.row_buckets``) too: the same program, kernels and names,
+    with 1 or 4 rows against the full pool."""
+    from repro.serve import executor as ex
+
+    model, params, pool = _serve_step_operands(one_chip, monkeypatch)
+    text = ex._prefill_step.lower(
+        model, params, _sds(one_chip, (rows, 96), jnp.int32),
+        _sds(one_chip, (rows,), jnp.int32), pool, pool,
+        _sds(one_chip, (rows, 6), jnp.int32)).compile().as_text()
+    assert text.startswith("HloModule jit__prefill_impl,")
+    assert any(line.lstrip().startswith("%flash_attention.")
+               for line in text.splitlines()
+               if "tpu_custom_call" in line and " = " in line)

@@ -26,7 +26,9 @@ from ``launch.mesh.kv_partition_axes`` (the same degradation rule as the
 executor's committed pool layout, so the shard a kernel sees IS the shard
 the executor placed there):
 
-  * pools ``[P, page, Hkv, hd]`` shard ``P(None, None, kv, hd)``;
+  * pools ``[P, page, Hkv, hd]`` shard ``P(None, None, kv, hd)``, and the
+    stacked ``[L, P, page, Hkv, hd]`` pools decode reads in place shard
+    ``P(None, None, None, kv, hd)``;
   * the page table and every scalar-prefetch operand (lens/starts/
     seq_lens) pass through replicated — the satp analogue every shard
     reads coherently, so page-table translation needs no communication;
@@ -36,8 +38,9 @@ the executor placed there):
     along Hkv — no cross-shard reduction, no collective;
   * head_dim ('hd') sharding cuts the QK contraction axis, so the paged
     attention bodies ``all_gather`` K/V pool slices to full head_dim
-    (tiled, one concat-sized collective per call) and then claim the
-    replicated output every shard computed identically.  The paged copies
+    (tiled, one concat-sized collective per call; decode slices its one
+    layer out of the stack first) and then claim the replicated output
+    every shard computed identically.  The paged copies
     never contract: they stay collective-free even under 'hd'.
 
 The sharded serving executor dispatches through a mesh-bound model twin
@@ -149,24 +152,41 @@ def flash_attention(
     return out[:, :, :sq]
 
 
-paged_decode_attention = jax.jit(
-    lambda q, k_pool, v_pool, page_table, seq_lens, *, page_size,
-    scale=None, window=None, use_kernel=True, kv_scale=None: (
-        _paged_attn_kernel(
-            q, k_pool, v_pool, page_table, seq_lens,
-            page_size=page_size, scale=scale, window=window,
-            kv_scale=kv_scale,
-        )
-        if use_kernel
-        else ref.paged_decode_attention_ref(
-            q, k_pool, v_pool, page_table, seq_lens,
-            page_size=page_size, scale=scale, window=window,
-            kv_scale=kv_scale,
-        )
-    ),
+@functools.partial(
+    jax.jit,
     static_argnames=("page_size", "scale", "window", "use_kernel",
                      "kv_scale"),
 )
+def paged_decode_attention(
+    q: jax.Array,            # [B, Hkv, G, D]
+    k_pool: jax.Array,       # [L, P, page, Hkv, D] or [P, page, Hkv, D]
+    v_pool: jax.Array,
+    page_table: jax.Array,   # [B, max_pages] int32
+    seq_lens: jax.Array,     # [B] int32
+    layer: jax.Array | int = 0,
+    *,
+    page_size: int,
+    scale: float | None = None,
+    window: int | None = None,
+    use_kernel: bool = True,
+    kv_scale: float | None = None,
+) -> jax.Array:
+    """Paged decode attention over layer ``layer`` of a stacked pool (a
+    4-D pool is a one-layer stack).  The kernel reads the stack in place;
+    the oracle slices the layer out."""
+    if use_kernel:
+        return _paged_attn_kernel(
+            q, k_pool, v_pool, page_table, seq_lens, layer,
+            page_size=page_size, scale=scale, window=window,
+            kv_scale=kv_scale,
+        )
+    if k_pool.ndim == 5:
+        k_pool = jax.lax.dynamic_index_in_dim(k_pool, layer, keepdims=False)
+        v_pool = jax.lax.dynamic_index_in_dim(v_pool, layer, keepdims=False)
+    return ref.paged_decode_attention_ref(
+        q, k_pool, v_pool, page_table, seq_lens,
+        page_size=page_size, scale=scale, window=window, kv_scale=kv_scale,
+    )
 
 
 @functools.partial(
@@ -409,10 +429,11 @@ def paged_copy_at_sharded(
 
 def paged_decode_attention_sharded(
     q: jax.Array,            # [B, Hkv, G, D]
-    k_pool: jax.Array,       # [P, page, Hkv, D]
-    v_pool: jax.Array,       # [P, page, Hkv, D]
+    k_pool: jax.Array,       # [L, P, page, Hkv, D] or [P, page, Hkv, D]
+    v_pool: jax.Array,
     page_table: jax.Array,   # [B, max_pages] int32
     seq_lens: jax.Array,     # [B] int32
+    layer: jax.Array | int = 0,
     *,
     page_size: int,
     mesh,
@@ -425,36 +446,45 @@ def paged_decode_attention_sharded(
 
     'kv' shards Hkv on q AND the pools — the per-head online softmax makes
     each device's heads fully independent (no collective; outputs
-    concatenate along Hkv via the out spec).  'hd' shards only the pools:
-    it cuts the QK contraction, so the body all-gathers K/V to full
-    head_dim and every 'hd' shard computes the identical (replicated)
-    output.  q and the output stay replicated over 'hd'.
+    concatenate along Hkv via the out spec); each device reads layer
+    ``layer`` of its local stack in place.  'hd' shards only the pools:
+    it cuts the QK contraction, so the body slices out the one layer and
+    all-gathers it to full head_dim, and every 'hd' shard computes the
+    identical (replicated) output.  q and the output stay replicated over
+    'hd'.
     """
     if mesh is None or mesh.size == 1:
         return paged_decode_attention(
-            q, k_pool, v_pool, page_table, seq_lens, page_size=page_size,
-            scale=scale, window=window, use_kernel=use_kernel,
-            kv_scale=kv_scale,
+            q, k_pool, v_pool, page_table, seq_lens, layer,
+            page_size=page_size, scale=scale, window=window,
+            use_kernel=use_kernel, kv_scale=kv_scale,
         )
     kv_ax, hd_ax = _kv_axes(mesh, q.shape[1], q.shape[3])
-    pool_spec = jax.sharding.PartitionSpec(None, None, kv_ax, hd_ax)
+    lead = (None,) * (k_pool.ndim - 2)
+    pool_spec = jax.sharding.PartitionSpec(*lead, kv_ax, hd_ax)
     q_spec = jax.sharding.PartitionSpec(None, kv_ax, None, None)
     rep = jax.sharding.PartitionSpec()
     gather_hd = hd_ax is not None and mesh.shape[hd_ax] > 1
+    layer = jnp.asarray(layer, jnp.int32)
 
-    def body(q_l, kp_l, vp_l, pt, ln):
+    def body(q_l, kp_l, vp_l, pt, ln, li):
         if gather_hd:
+            # one layer's pool crosses the mesh, not the whole stack
+            if kp_l.ndim == 5:
+                kp_l = jax.lax.dynamic_index_in_dim(kp_l, li, keepdims=False)
+                vp_l = jax.lax.dynamic_index_in_dim(vp_l, li, keepdims=False)
+                li = 0
             kp_l = jax.lax.all_gather(kp_l, hd_ax, axis=-1, tiled=True)
             vp_l = jax.lax.all_gather(vp_l, hd_ax, axis=-1, tiled=True)
         return paged_decode_attention(
-            q_l, kp_l, vp_l, pt, ln, page_size=page_size,
+            q_l, kp_l, vp_l, pt, ln, li, page_size=page_size,
             scale=scale, window=window, use_kernel=use_kernel,
             kv_scale=kv_scale,
         )
 
     return _shard_map(
-        body, mesh, (q_spec, pool_spec, pool_spec, rep, rep), q_spec
-    )(q, k_pool, v_pool, page_table, seq_lens)
+        body, mesh, (q_spec, pool_spec, pool_spec, rep, rep, rep), q_spec
+    )(q, k_pool, v_pool, page_table, seq_lens, layer)
 
 
 def paged_prefill_attention_sharded(

@@ -595,68 +595,62 @@ class TransformerLM:
         hkv, hd, g = cfg.num_kv_heads, cfg.head_dim, cfg.q_per_kv
         pos = state.seq_lens                      # [B] position of new token
         x = self.embed(params, tokens)[:, None, :]  # [B, 1, D]
-        # flat physical row of the new token in every pool (one translation
-        # per element here — B independent sequences, B translations).
-        # Inactive batch slots (unmapped page-table rows) are routed to the
-        # pool's LAST row, which the serving engine reserves as scratch —
-        # never to a live frame.
+        # (frame, offset) of the new token in every layer's pool (one
+        # translation per element here — B independent sequences, B
+        # translations).  Inactive batch slots (unmapped page-table rows)
+        # are routed to the pool's LAST row, which the serving engine
+        # reserves as scratch — never to a live frame.
         frames = jnp.take_along_axis(
             state.page_table, (pos // page)[:, None], axis=1
         )[:, 0]
-        n_rows = state.k_pools.shape[1] * page
-        rows = jnp.where(
-            frames < 0, n_rows - 1, frames * page + pos % page
-        )                                                       # [B]
-        new_lens = jnp.where(frames < 0, pos, pos + 1)
+        inactive = frames < 0
+        frames = jnp.where(inactive, state.k_pools.shape[1] - 1, frames)
+        offs = jnp.where(inactive, page - 1, pos % page)            # [B]
+        new_lens = jnp.where(inactive, pos, pos + 1)
+        kv_scale = (1.0 / self.KV_INT8_SCALE
+                    if self.kv_dtype == "int8" else None)
         mesh = self._serve_kernel_mesh()
 
-        def layer(block_p, x, k_pool, v_pool, is_moe):
+        # The stacked [L, P, page, Hkv, hd] pools ride the scan's carry
+        # whole, from the donated input to the output: each layer writes
+        # its new rows into the stack in place and the kernel reads layer
+        # ``li`` of the stack by index, so no layer's pool is sliced out,
+        # re-laid-out or re-stacked.
+        def layer(block_p, x, k_pools, v_pools, li, is_moe):
             q, k, v = self._block_serve_qkv(block_p, x, pos[:, None])
             # the single-token row scatter is plain jnp — GSPMD shards it
-            k_pool = k_pool.reshape(-1, hkv, hd).at[rows].set(
-                self._kv_quant(k[:, 0])
-            ).reshape(k_pool.shape)
-            v_pool = v_pool.reshape(-1, hkv, hd).at[rows].set(
-                self._kv_quant(v[:, 0])
-            ).reshape(v_pool.shape)
+            k_pools = k_pools.at[li, frames, offs].set(self._kv_quant(k[:, 0]))
+            v_pools = v_pools.at[li, frames, offs].set(self._kv_quant(v[:, 0]))
             qh = q[:, 0].reshape(b, hkv, g, hd)
-            kv_scale = (1.0 / self.KV_INT8_SCALE
-                        if self.kv_dtype == "int8" else None)
             if mesh is not None:
                 o = ops.paged_decode_attention_sharded(
-                    qh, k_pool, v_pool, state.page_table, new_lens,
+                    qh, k_pools, v_pools, state.page_table, new_lens, li,
                     page_size=page, mesh=mesh, kv_scale=kv_scale,
                 )
             else:
                 o = ops.paged_decode_attention(
-                    qh, k_pool, v_pool, state.page_table, new_lens,
+                    qh, k_pools, v_pools, state.page_table, new_lens, li,
                     page_size=page, use_kernel=self.use_kernels,
                     kv_scale=kv_scale,
                 )                                 # [B, Hkv, G, hd]
             x = x + (o.reshape(b, 1, hkv * g * hd) @ block_p["attn"]["wo"])
             x = self._ffn_serve(block_p, x, is_moe)
-            return x, k_pool, v_pool
+            return x, k_pools, v_pools
 
         def body(carry, xs):
-            x = carry
-            sb, k_pools_g, v_pools_g = xs
-            kps, vps = [], []
+            x, k_pools, v_pools = carry
+            sb, si = xs
             for i in range(self.moe_every):
-                x, kp, vp = layer(
-                    sb[f"sub{i}"], x, k_pools_g[i], v_pools_g[i],
-                    self._is_moe_sub(i),
+                x, k_pools, v_pools = layer(
+                    sb[f"sub{i}"], x, k_pools, v_pools,
+                    si * self.moe_every + i, self._is_moe_sub(i),
                 )
-                kps.append(kp)
-                vps.append(vp)
-            return x, (jnp.stack(kps), jnp.stack(vps))
+            return (x, k_pools, v_pools), None
 
-        x, (k_pools, v_pools) = jax.lax.scan(
-            body, x,
-            (params["blocks"], self._group_pools(state.k_pools),
-             self._group_pools(state.v_pools)),
+        (x, k_pools, v_pools), _ = jax.lax.scan(
+            body, (x, state.k_pools, state.v_pools),
+            (params["blocks"], jnp.arange(self.n_super, dtype=jnp.int32)),
         )
-        k_pools = self._ungroup_pools(k_pools)
-        v_pools = self._ungroup_pools(v_pools)
         x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits = self.logits_fn(params, x[:, 0])
         return logits, PagedKVState(

@@ -6,7 +6,8 @@ against one chip of a described ``v5e:2x2`` topology and compiles it with
 the TPU compiler, which refuses what interpret mode accepts: block shapes
 off the (8, 128) tiling, and more VMEM than a kernel may use.  The
 executor's prefill and decode steps are compiled whole too, to pin the
-program and kernel names that the chip benchmark finds in a device trace.
+program and kernel names that the chip benchmark finds in a device trace,
+and to keep the decode steps free of pool-sized copies.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -16,9 +17,11 @@ imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import flash_attention as flash_module
@@ -37,7 +40,7 @@ DTYPES = {"bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -49,8 +52,13 @@ def one_chip():
     # persistent cache without that chip; keep it out of the cache
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype):
@@ -65,16 +73,23 @@ def _compile(fn, *args):
 
 @pytest.mark.parametrize("kv", sorted(DTYPES))
 def test_paged_decode_attention_compiles(one_chip, kv):
+    """The 4-D pool, and a stack of 7 layers' pools read at a traced
+    layer index."""
     kv_scale = 1 / 24 if kv == "int8" else None
-    pool = _sds(one_chip, (FRAMES, PAGE, HKV, D), DTYPES[kv])
-    _compile(
-        lambda q, k, v, pt, lens: paged_decode_attention(
-            q, k, v, pt, lens, page_size=PAGE, kv_scale=kv_scale,
-            interpret=False),
-        _sds(one_chip, (BATCH, HKV, G, D), jnp.bfloat16), pool, pool,
+    operands = (
+        _sds(one_chip, (BATCH, HKV, G, D), jnp.bfloat16),
         _sds(one_chip, (BATCH, MAX_PAGES), jnp.int32),
         _sds(one_chip, (BATCH,), jnp.int32),
     )
+    for shape in [(FRAMES, PAGE, HKV, D), (7, FRAMES, PAGE, HKV, D)]:
+        pool = _sds(one_chip, shape, DTYPES[kv])
+        _compile(
+            lambda q, k, v, pt, lens, li: paged_decode_attention(
+                q, k, v, pt, lens, li, page_size=PAGE, kv_scale=kv_scale,
+                interpret=False),
+            operands[0], pool, pool, *operands[1:],
+            _sds(one_chip, (), jnp.int32),
+        )
 
 
 @pytest.mark.parametrize("chunk", [24, 512])
@@ -124,10 +139,13 @@ def test_flash_attention_compiles(one_chip, seq):
     )
 
 
-def _serve_step_operands(one_chip, monkeypatch):
-    """A kernel model at qwen2-7b attention widths (one layer, narrow MLP
-    and vocabulary), kernels compiled rather than interpreted, with its
-    parameters and KV pool as shapes on the described chip."""
+def _serve_step_operands(one_chip, monkeypatch, *, layers=1, frames=48,
+                         kv_dtype="native", mesh=None):
+    """A kernel model at qwen2-7b attention widths (``layers`` layers,
+    narrow MLP and vocabulary), kernels compiled rather than interpreted,
+    with its parameters and KV pool of ``frames`` pages as shapes on the
+    described chip (``one_chip``; the model's kernels shard_map over
+    ``mesh`` when one is given)."""
     from repro.models import build_model
     from repro.models.config import ModelConfig
 
@@ -135,14 +153,14 @@ def _serve_step_operands(one_chip, monkeypatch):
     for mod in (paged_module, flash_module, copy_module):
         monkeypatch.setattr(mod, "should_interpret", lambda: False)
     model = build_model(ModelConfig(
-        name="names", family="dense", num_layers=1, d_model=512,
+        name="names", family="dense", num_layers=layers, d_model=512,
         num_heads=HKV * G, num_kv_heads=HKV, d_ff=512, vocab_size=1024,
         head_dim=D, qkv_bias=True, param_dtype="bfloat16"),
-        remat=False, use_kernels=True)
+        remat=False, use_kernels=True, kv_dtype=kv_dtype, kernel_mesh=mesh)
     params = jax.tree_util.tree_map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
-    kv = jax.eval_shape(lambda: model.init_kv_state(BATCH, 48, PAGE, 6))
+    kv = jax.eval_shape(lambda: model.init_kv_state(BATCH, frames, PAGE, 6))
     pool = _sds(one_chip, kv.k_pools.shape, kv.k_pools.dtype)
     return model, params, pool
 
@@ -161,29 +179,127 @@ def test_serve_steps_keep_their_trace_names(one_chip, monkeypatch, step):
     def sds(shape, dtype):
         return _sds(one_chip, shape, dtype)
 
-    rows = sds((BATCH,), jnp.int32)
-    table = sds((BATCH, 6), jnp.int32)
     if step == "prefill":
         lowered = ex._prefill_step.lower(
-            model, params, sds((BATCH, 96), jnp.int32), rows, pool, pool,
-            table)
+            model, params, sds((BATCH, 96), jnp.int32),
+            sds((BATCH,), jnp.int32), pool, pool, sds((BATCH, 6), jnp.int32))
         names = ("_prefill_impl", "flash_attention")
-    elif step == "decode":
-        lowered = ex._decode_step.lower(
-            model, params, rows, pool, pool, table, rows,
-            sds((BATCH,), jnp.bool_))
-        names = ("_decode_impl", "paged_decode_attention")
     else:
-        lowered = ex._decode_multi_step.lower(
-            model, params, rows, pool, pool, table, rows, rows,
-            sds((2,), jnp.uint32), 1.0, 2, True)
-        names = ("_decode_multi_impl", "paged_decode_attention")
+        lowered = _lower_decode(step, model, params, pool, sds)
+        names = (f"_{step}_impl", "paged_decode_attention")
     text = lowered.compile().as_text()
     assert text.startswith(f"HloModule jit_{names[0]},")
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and " = " in line]
     assert any(line.lstrip().startswith(f"%{names[1]}.")
                for line in kernels), kernels[:3]
+
+
+def _lower_decode(step, model, params, pool, sds):
+    """The executor's single-chip ``decode`` or ``decode_multi`` step,
+    lowered on operand shapes made by ``sds(shape, dtype)``."""
+    from repro.serve import executor as ex
+
+    rows = sds((BATCH,), jnp.int32)
+    table = sds((BATCH, 6), jnp.int32)
+    if step == "decode":
+        return ex._decode_step.lower(
+            model, params, rows, pool, pool, table, rows,
+            sds((BATCH,), jnp.bool_))
+    return ex._decode_multi_step.lower(
+        model, params, rows, pool, pool, table, rows, rows,
+        sds((2,), jnp.uint32), 1.0, 2, True)
+
+
+def _hlo_op(line):
+    """(name, result shape, opcode) of one HLO instruction line."""
+    name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+    if rest.startswith("("):          # tuple shape: up to its closing paren
+        depth = 0
+        for i, c in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(c, 0)
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 1:]
+    else:
+        shape, rest = rest.split(" ", 1)
+    return name, shape, rest.strip().split("(", 1)[0]
+
+
+@pytest.mark.parametrize("kv", sorted(DTYPES))
+@pytest.mark.parametrize("step", ["decode", "decode_multi"])
+def test_decode_keeps_the_pools_in_place(one_chip, monkeypatch, step, kv):
+    """The decode step carries the stacked K/V pools whole from the donated
+    input to the output: every layer's new rows are scattered into the
+    stack in place and the kernel reads the stack by layer index.  No op
+    whose shape holds the pool's frame count may appear besides
+    parameters, bitcasts, tuples, the layer loop, the kernel and the
+    in-place scatter: no copy, reshape, dynamic-slice or
+    dynamic-update-slice of a pool or a stack."""
+    frames = 1031                      # a count no other dim shares
+    model, params, pool = _serve_step_operands(
+        one_chip, monkeypatch, layers=3, frames=frames,
+        kv_dtype="int8" if kv == "int8" else "native")
+    text = _lower_decode(step, model, params, pool,
+                         lambda shape, dtype: _sds(one_chip, shape, dtype)
+                         ).compile().as_text()
+    _assert_pools_in_place(text, frames)
+
+
+def test_mesh_decode_keeps_the_pools_in_place(topo, monkeypatch):
+    """The serve mesh's decode step, one KV head per chip (kv = 4 over the
+    described 2x2): each chip's stack stays in place too; its lone head is
+    read as a (page, D) block, a bitcast of the stored layout."""
+    from repro.serve import executor as ex
+
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices).reshape(4, 1),
+                             ("kv", "hd"))
+    # a pool too large for the compiler to stage a chip's share in VMEM
+    frames = 8191
+    # shapes with no sharding: the mesh jit's in_shardings place them
+    model, params, pool = _serve_step_operands(
+        None, monkeypatch, layers=3, frames=frames, mesh=mesh)
+    rows = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+    text = ex._sharded_steps(model, mesh)["decode"].lower(
+        params, rows, pool, pool, jax.ShapeDtypeStruct((BATCH, 6), jnp.int32),
+        rows, jax.ShapeDtypeStruct((BATCH,), jnp.bool_)).compile().as_text()
+    _assert_pools_in_place(text, frames)
+
+
+def _assert_pools_in_place(text, frames):
+    """No op whose shape holds the pool's ``frames`` in the compiled
+    ``text`` but parameters, bitcasts, tuples, the layer loop, the decode
+    kernel and the K and V scatters (a scatter, or a fusion rooted in
+    one), and both pools donated."""
+    header = text.splitlines()[0]
+    assert header.count("may-alias") >= 2, header[:300]   # both pools donated
+
+    roots, comp, ops = {}, None, []
+    for line in text.splitlines()[1:]:
+        if line.endswith("{") and " = " not in line:    # a computation
+            comp = line.removeprefix("ENTRY ").split(" ", 1)[0]
+        elif line.lstrip().startswith(("%", "ROOT %")) and " = " in line:
+            ops.append((line, *_hlo_op(line)))
+            if line.lstrip().startswith("ROOT "):
+                roots[comp] = ops[-1][3]
+    allowed = {"parameter", "bitcast", "tuple", "get-tuple-element",
+               "while", "scatter"}
+    pool_sized = re.compile(rf"[\[,]{frames}[,\]]")
+    found = []
+    for line, name, shape, op in ops:
+        if not pool_sized.search(shape) or op in allowed:
+            continue
+        if op == "custom-call" and name.startswith("%paged_decode_attention."):
+            continue
+        if op == "fusion":
+            called = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+            if roots.get(called) == "scatter":
+                continue
+        found.append(f"{op}: {line.strip()[:160]}")
+    assert not found, found
+    # the K and the V scatter
+    assert sum(op == "scatter" and bool(pool_sized.search(shape))
+               for _, _, shape, op in ops) >= 2
 
 
 @pytest.mark.parametrize("rows", [1, 4])

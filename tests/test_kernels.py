@@ -177,6 +177,68 @@ class TestPagedDecodeAttention:
         np.testing.assert_array_equal(np.asarray(out[1:]), 0.0)
 
 
+def make_stacked_case(kv, hkv, *, layers=3, page=4, g=2, d=16, seed=0):
+    """Stacked ``[L, P, page, Hkv, D]`` pools and a table whose rows hold
+    unmapped (-1) tails, an empty sequence and pages past ``seq_len``."""
+    lens = np.array([9, 0, 21, 3], np.int32)
+    max_pages = 7
+    n_frames = 24
+    rng = np.random.default_rng(seed)
+    frames = rng.permutation(n_frames)
+    table = np.full((len(lens), max_pages), -1, np.int32)
+    fi = 0
+    for row, n in enumerate(lens):
+        # one mapped page past seq_len on every row
+        need = -(-int(n) // page) + 1
+        table[row, :need] = frames[fi: fi + need]
+        fi += need
+    ks = jax.random.split(jax.random.fold_in(KEY, seed), 3)
+    shape = (layers, n_frames, page, hkv, d)
+    if kv == "int8":
+        k = jax.random.randint(ks[0], shape, -127, 128).astype(jnp.int8)
+        v = jax.random.randint(ks[1], shape, -127, 128).astype(jnp.int8)
+    else:
+        k, v = rand(ks[0], shape, jnp.bfloat16), rand(ks[1], shape, jnp.bfloat16)
+    q = rand(ks[2], (len(lens), hkv, g, d), jnp.bfloat16)
+    return q, k, v, jnp.asarray(table), jnp.asarray(lens)
+
+
+class TestPagedDecodeAttentionStacked:
+    """The kernel reads layer ``layer`` of a stacked pool in place: equal
+    to the 4-D call on that layer's pool, and to the oracle."""
+
+    @pytest.mark.parametrize("window", [None, 6])
+    @pytest.mark.parametrize("hkv", [1, 4])
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_stack_equals_layer_slice(self, kv, hkv, window):
+        q, k, v, pt, sl = make_stacked_case(kv, hkv, seed=hkv)
+        kw = dict(page_size=4, window=window,
+                  kv_scale=1 / 24 if kv == "int8" else None)
+        at = jax.jit(lambda li: ops.paged_decode_attention(
+            q, k, v, pt, sl, li, **kw))
+        for li in range(k.shape[0]):
+            out = at(jnp.int32(li))
+            one = ops.paged_decode_attention(q, k[li], v[li], pt, sl, **kw)
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+            expect = ops.paged_decode_attention(
+                q, k, v, pt, sl, li, use_kernel=False, **kw)
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(expect, np.float32),
+                rtol=2e-2, atol=2e-2)
+            # the empty sequence attends nothing
+            np.testing.assert_array_equal(np.asarray(out[1], np.float32), 0.0)
+
+    def test_layers_differ(self):
+        """The index map follows ``layer``: each layer's pool gives its
+        own output."""
+        q, k, v, pt, sl = make_stacked_case("bf16", 4, seed=5)
+        outs = [np.asarray(ops.paged_decode_attention(
+            q, k, v, pt, sl, li, page_size=4), np.float32)
+            for li in range(k.shape[0])]
+        assert not np.allclose(outs[0], outs[1])
+        assert not np.allclose(outs[1], outs[2])
+
+
 # ---------------------------------------------------------------------------
 # paged copy / gather
 # ---------------------------------------------------------------------------

@@ -144,6 +144,38 @@ class TestShardedDecodeAttention:
                                    err_msg="sharded != jnp oracle")
 
 
+class TestShardedStackedDecodeAttention:
+    """The stacked ``[L, P, page, Hkv, D]`` call through the mesh: a 'kv'
+    split reads the local stack in place, an 'hd' split slices the layer
+    before its all-gather."""
+
+    @pytest.mark.parametrize("split", ["kv", "hd"])
+    def test_stack_vs_layer_slice(self, split):
+        shape = (2, 1) if split == "kv" else (1, 2)
+        mesh = jax.sharding.Mesh(
+            np.asarray(jax.devices()[:2]).reshape(shape), ("kv", "hd"))
+        layers = 3
+        q, kp, vp, tab, sl = _decode_case(4, [9, 6, 17], seed=layers)
+        kps = jnp.stack([kp * (li + 1) for li in range(layers)])
+        vps = jnp.stack([vp - li for li in range(layers)])
+        at = jax.jit(lambda li: ops.paged_decode_attention_sharded(
+            q, kps, vps, tab, sl, li, page_size=4, mesh=mesh))
+        kv_ax, _ = kv_partition_axes(mesh, HKV, D)
+        for li in range(layers):
+            out_sh = at(jnp.int32(li))
+            out_k = ops.paged_decode_attention(
+                q, kps[li], vps[li], tab, sl, page_size=4, use_kernel=True)
+            out_r = ops.paged_decode_attention(
+                q, kps, vps, tab, sl, li, page_size=4, use_kernel=False)
+            _assert_spec(out_sh, mesh, None, kv_ax, None, None)
+            np.testing.assert_allclose(
+                out_sh, out_k, rtol=2e-5, atol=2e-5,
+                err_msg=f"layer {li}: sharded != single-device kernel")
+            np.testing.assert_allclose(
+                out_sh, out_r, rtol=2e-5, atol=2e-5,
+                err_msg=f"layer {li}: sharded != jnp oracle")
+
+
 class TestShardedPagedCopies:
     """tests/test_kernels.py's copy grids through the 4-D sharded entry
     points (the merged-W reshape happens inside the shard bodies)."""

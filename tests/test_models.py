@@ -6,6 +6,8 @@ requirement).  The golden consistency tests assert the serving contract:
 prefill + paged decode produce exactly the logits of the monolithic forward.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,15 +72,17 @@ def test_reduced_smoke_output_shapes(arch):
     assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
-def _golden_paged(arch, steps=3, tol=1e-3):
+def _golden_paged(arch, steps=3, tol=1e-3, num_layers=None, **model_kw):
     """prefill + decode through paged VM == monolithic forward.
 
     MoE models compare with the drop-free ragged dispatch on both sides
     (the sorted training dispatch drops tokens at capacity by design, so
     it cannot be the serving oracle)."""
     cfg = get_config(arch, reduced=True)
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
     kwargs = {"moe_dispatch": "ragged"} if cfg.family == "moe" else {}
-    model = build_model(cfg, remat=False, **kwargs)
+    model = build_model(cfg, remat=False, **kwargs, **model_kw)
     params = model.init(KEY)
     B, PROMPT, PAGE = 2, 10, 4
     tok_shape = (B, PROMPT + steps + 1) + (
@@ -129,6 +133,16 @@ def _golden_paged(arch, steps=3, tol=1e-3):
 def test_golden_paged_serving(arch):
     """Serving through the paged VM is exact vs the monolithic forward."""
     _golden_paged(arch)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-7b", "llama4-maverick-400b-a17b"])
+def test_golden_paged_serving_kernels(arch):
+    """The same through the Pallas kernels (interpreted here): decode
+    reads each layer's KV out of the stacked pools at that layer's index,
+    which interleaved MoE superblocks (llama4, two layers per scan step)
+    compute as superblock * 2 + sublayer; four layers make two
+    superblocks."""
+    _golden_paged(arch, num_layers=4, use_kernels=True)
 
 
 def test_golden_rwkv_serving():
